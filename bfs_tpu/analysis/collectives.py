@@ -17,7 +17,7 @@ Checked invariants, per analyzed program (:class:`~bfs_tpu.analysis.ir.Program`)
   because a per-superstep merge rides the ``graph`` axis; a refactor that
   drops the all-reduce produces per-shard-plausible wrong results with no
   runtime error.
-* **IR005c** — the ``shard_map`` result shardings (``out_names`` in the
+* **IR005c** — the ``shard_map`` result shardings (``out_specs`` in the
   lowered eqn) match the spec's ``expected_out_names``.  XLA will happily
   return per-shard state where the caller expects replicated state; every
   downstream consumer then silently reads shard 0.
@@ -33,18 +33,18 @@ the floor and are never flagged.
 
 from __future__ import annotations
 
-#: Primitives that move payload across mesh axes.  ``psum2`` is jax's
-#: post-0.4.30 spelling of psum inside shard_map.
+#: Primitives that move payload across mesh axes.  ``psum_invariant``
+#: is jax's spelling of psum inside shard_map.
 PAYLOAD_COLLECTIVES = frozenset({
-    "psum", "psum2", "pmin", "pmax", "ppermute", "pshuffle",
+    "psum", "psum_invariant", "pmin", "pmax", "ppermute", "pshuffle",
     "all_gather", "all_to_all", "reduce_scatter",
 })
 
 #: Axis-binding eqns that move no payload: mesh-coordinate reads and the
 #: replication-rewrite casts shard_map inserts automatically.  They never
-#: satisfy a required exchange axis and are never flagged — pbroadcast in
+#: satisfy a required exchange axis and are never flagged — pvary in
 #: particular appears in ANY shard_map body, collective or not.
-CONTROL_COLLECTIVES = frozenset({"axis_index", "pbroadcast", "pcast"})
+CONTROL_COLLECTIVES = frozenset({"axis_index", "pvary", "pcast"})
 
 _WIDE_DTYPES = frozenset({"int64", "uint64", "float64"})
 
@@ -65,13 +65,18 @@ def eqn_axis_names(eqn) -> tuple[str, ...]:
 
 
 def out_names_sets(eqn) -> tuple[frozenset, ...]:
-    """``shard_map`` eqn ``out_names`` (dim -> axis tuple dicts) as one
-    frozenset of axis names per flat output — the comparable form of the
-    declared ``out_specs``."""
-    return tuple(
-        frozenset(ax for axs in d.values() for ax in axs)
-        for d in eqn.params.get("out_names", ())
-    )
+    """``shard_map`` eqn ``out_specs`` (PartitionSpecs) as one frozenset
+    of axis names per flat output — the comparable form of the declared
+    ``out_specs``."""
+    out = []
+    for spec in eqn.params.get("out_specs", ()):
+        names = set()
+        for entry in spec:
+            if entry is None:
+                continue
+            names.update(entry if isinstance(entry, tuple) else (entry,))
+        out.append(frozenset(names))
+    return tuple(out)
 
 
 def check_collectives(prog, walked, make_finding):

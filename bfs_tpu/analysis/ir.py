@@ -30,7 +30,7 @@ and walked for invariants the AST cannot see:
 Unlike the AST half this module imports jax — it is loaded only by the
 ``--ir`` CLI path and the IR tests, never by ``bfs_tpu.analysis`` itself.
 Tracing every program costs seconds, so results are cached
-content-addressed (like the compile cache, models/bfs.compile_exe_cached):
+content-addressed (like JAX's persistent compile cache):
 the key hashes every ``bfs_tpu`` source file plus the jax version,
 backend, device count and the env knobs that select program flavors.
 Tier-1 reruns are a cache hit unless the package actually changed.
@@ -62,8 +62,7 @@ _FLAVOR_ENV = knobs.flavor_env("ir")
 
 #: Primitives whose presence in a loop body is a host round-trip (IR002).
 _CALLBACK_PRIMS = frozenset({
-    "pure_callback", "io_callback", "debug_callback", "callback",
-    "outside_call", "host_callback_call",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
 })
 _TRANSFER_PRIMS = frozenset({"device_put"})
 
@@ -188,7 +187,7 @@ def _check_donation(prog: Program, closed, make_finding):
     donated = [False] * len(invars)
     var_index = {id(v): i for i, v in enumerate(invars)}
     for eqn, _ctx in walk_eqns(closed.jaxpr):
-        if eqn.primitive.name != "pjit":
+        if eqn.primitive.name != "jit":
             continue
         flags = eqn.params.get("donated_invars") or ()
         for j, v in enumerate(eqn.invars):

@@ -549,7 +549,7 @@ def labels_key(graph, k: int) -> str:
 
 
 def load_or_build_labels(graph, k: int, *, cache: LayoutCache | None = None,
-                         engine: str = "pull",
+                         engine: str = "pull", layout=None,
                          ckpt_dir: str | os.PathLike | None = None):
     """``(LabelIndex, info)`` — the serve label tier's landmark index,
     disk-cached as a sidecar bundle next to the layout bundle (info
@@ -569,7 +569,7 @@ def load_or_build_labels(graph, k: int, *, cache: LayoutCache | None = None,
         kind="labels",
         key_fn=lambda: labels_key(graph, k),
         build_fn=lambda: build_label_index(
-            graph, k, engine=engine, ckpt_dir=ckpt_dir
+            graph, k, engine=engine, layout=layout, ckpt_dir=ckpt_dir
         ),
         to_arrays=labels_to_arrays,
         from_arrays=labels_from_arrays,

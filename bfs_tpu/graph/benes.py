@@ -67,6 +67,12 @@ def native_available() -> bool:
     return _LIB.available()
 
 
+def require_native() -> None:
+    """Raise RuntimeError, with the build error, when the router is
+    missing: the relay layout has no other router on the host."""
+    _LIB.require()
+
+
 def num_stages(n: int) -> int:
     return 2 * (int(n).bit_length() - 1) - 1
 
@@ -85,9 +91,7 @@ def route(perm: np.ndarray, *, bit_major: bool = False) -> np.ndarray:
     — the layout :func:`bfs_tpu.ops.relay.apply_benes` consumes; the default
     word-major layout matches :func:`apply_network_numpy`'s default.
     """
-    lib = _LIB.load()
-    if lib is None:
-        raise RuntimeError("native benes router unavailable")
+    lib = _LIB.require()
     perm = np.ascontiguousarray(perm, dtype=np.int64)
     n = int(perm.shape[0])
     if n < 2 or n & (n - 1):
@@ -247,9 +251,7 @@ def route_std(perm: np.ndarray, *, trusted: bool = False) -> np.ndarray:
     native router (``benes_route_i32``).  This is the packing the v4 device
     kernels consume directly; no transpose pass.  ``len(perm)`` must be a
     power of two in [32, 2^30]."""
-    lib = _LIB.load()
-    if lib is None:
-        raise RuntimeError("native benes router unavailable")
+    lib = _LIB.require()
     perm = np.ascontiguousarray(perm, dtype=np.int32)
     n = int(perm.shape[0])
     if n < 32 or n & (n - 1):

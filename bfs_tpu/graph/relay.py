@@ -672,8 +672,7 @@ def build_relay_graph(graph: Graph | DeviceGraph) -> RelayGraph:
     segments as its measured host arm, overlapped with the routes.
     """
     _ensure_build_log()
-    if not benes.native_available():
-        raise RuntimeError("relay engine requires the native benes router")
+    benes.require_native()
     src, dst, v, e = extract_edges(graph)
 
     with _phase("degrees"):
@@ -835,8 +834,7 @@ def build_sharded_relay_graph(
     goes through ``old2new``/``new2old``).
     """
     _ensure_build_log()
-    if not benes.native_available():
-        raise RuntimeError("relay engine requires the native benes router")
+    benes.require_native()
     if num_shards < 1:
         raise ValueError("num_shards must be >= 1")
     from .csr import _sorted_by_dst, unpad_edges

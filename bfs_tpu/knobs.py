@@ -23,9 +23,8 @@ Accessors:
   silently change what a capture measured (the resolve_direction
   contract, applied uniformly).
 * :func:`raw` — the unparsed read (``os.environ.get``, None when unset)
-  for the path knobs where unset-vs-explicitly-empty differ
-  (``BFS_TPU_EXE_CACHE=""`` means *disabled*, unset means *default
-  dir*) and for key builders that hash raw strings.
+  for the path knobs where unset-vs-explicitly-empty differ and for key
+  builders that hash raw strings.
 
 ``affects`` domains (each a derived tuple somewhere — KNB002 verifies):
 
@@ -392,7 +391,7 @@ KNOBS: dict[str, Knob] = {k.name: k for k in (
        "lane-compacted relay kernel variant (disables tile-major when "
        "set)",
        affects=_FLAVOR, canary="2"),
-    _k("BFS_TPU_TILE_ROWS", "int", "2048", _int(1),
+    _k("BFS_TPU_TILE_ROWS", "int", "256", _int(1),
        "relay kernel rows per grid tile",
        affects=("pal",), scope="import", canary="8k"),
     _k("BFS_TPU_OUTER_TT", "int", "64", _int(1),
@@ -445,9 +444,6 @@ KNOBS: dict[str, Knob] = {k.name: k for k in (
        "<repo>/.bench_cache)"),
     _k("BFS_TPU_JOURNAL_DIR", "path", "", _parse_str,
        "run-journal directory (default <cache root>/journal)"),
-    _k("BFS_TPU_EXE_CACHE", "path", "", _parse_str,
-       "serialized-executable cache dir; explicitly empty = disabled, "
-       "unset = <cache root>/exe"),
     _k("BFS_TPU_IR_CACHE", "path", "", _parse_str,
        "IR-lint result cache dir (default <repo>/.bench_cache/ir)"),
     _k("BFS_TPU_HLO_CACHE", "path", "", _parse_str,

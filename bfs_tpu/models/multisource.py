@@ -341,7 +341,8 @@ def bfs_multi(
     if engine == "relay":
         from .bfs import RelayEngine
 
-        return RelayEngine(graph).run_multi(sources, max_levels=max_levels)
+        eng = graph if isinstance(graph, RelayEngine) else RelayEngine(graph)
+        return eng.run_multi(sources, max_levels=max_levels)
     requested = max_levels if max_levels is not None else graph.num_vertices
     packed = resolve_packed(packed_parent_fits(graph.num_vertices))
     state, v = bfs_multi_device(

@@ -252,99 +252,149 @@ def expand_frontier_mxu_xla(
     return out.reshape(-1)[:cols]
 
 
+#: Tiles whose (row, column) indices are DMA'd into SMEM at a time: the
+#: whole [ntp] index arrays outgrow the 1 MiB SMEM past ~100k tiles.
+_IDX_CHUNK = 1024
+
+#: Tiles per frontier-word DMA block: the [ntp, 4] per-tile frontier
+#: words travel lane-dense as [ntp/32, 128] rows, DMA'd 8 rows at a time.
+_F_ROWS = 8
+_F_BLOCK = _F_ROWS * TILE // TILE_WORDS  # 256 tiles per (8, 128) block
+
+
 def _mxu_kernel_factory():
     """One column-superblock per grid step; the per-tile inner loop DMAs
     the frontier block first and early-outs (no tile DMA, no matmul) when
-    it is zero."""
+    it is zero.
+
+    Every DMA moves one (8, 128) block, the TPU's native 32-bit tiling:
+    tiles travel word-major in pairs (``[ntp/2, 8, 128]``, row ``4*(t&1)+j``
+    = word ``j`` of tile ``t`` over its 128 source rows), frontier words
+    lane-dense (:data:`_F_BLOCK` tiles per block) and key rows 8 at a
+    time; the kernel picks its rows out in registers.  Mosaic has no
+    unsigned min, so candidates accumulate as int32 with INT32_MAX for
+    SENT (ids are < 2^31) and the caller maps the sentinel back."""
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
 
     # bfs_tpu: hot
-    def kernel(sb_ref, cl_ref, row_ref, tiles_hbm, fblk_hbm, keys_hbm,
-               o_ref, tbuf, fbuf, kbuf, sem):
+    def kernel(sb_ref, idx_hbm, tiles_hbm, fblk_hbm, keys_hbm,
+               o_ref, ibuf, tbuf, fbuf, kbuf, sem):
         from jax.experimental.pallas import tpu as pltpu
 
         pid = pl.program_id(0)
-        o_ref[...] = jnp.full((SB_TILES, TILE), SENT, jnp.uint32)
+        imax = jnp.int32(np.iinfo(np.int32).max)
+        o_ref[...] = jnp.full((SB_TILES, TILE), imax, jnp.int32)
         t0 = sb_ref[pid]
         t1 = sb_ref[pid + 1]
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, TILE), 1)
 
         def body(t, carry):
+            @pl.when((t == t0) | (t % _IDX_CHUNK == 0))
+            def _():
+                cp_i = pltpu.make_async_copy(
+                    idx_hbm.at[t // _IDX_CHUNK], ibuf, sem.at[3]
+                )
+                cp_i.start()
+                cp_i.wait()
+
             cp_f = pltpu.make_async_copy(
-                fblk_hbm.at[t], fbuf.at[0], sem.at[0]
+                fblk_hbm.at[t // _F_BLOCK], fbuf, sem.at[0]
             )
             cp_f.start()
             cp_f.wait()
-            nz = (fbuf[0] != 0).any()
+            frow = fbuf[pl.ds((t // (_F_BLOCK // _F_ROWS)) % _F_ROWS, 1), :]
+            frow = pltpu.bitcast(frow, jnp.int32)
+            base = (t % (_F_BLOCK // _F_ROWS)) * TILE_WORDS
+            # The tile's 4 frontier words, each as a (1, 1) vector.
+            fw4 = [
+                jnp.sum(jnp.where(lane == base + j, frow, 0), axis=1,
+                        keepdims=True)
+                for j in range(TILE_WORDS)
+            ]
+            nz = jnp.max(
+                ((fw4[0] | fw4[1] | fw4[2] | fw4[3]) != 0).astype(jnp.int32)
+            ) > 0
 
             @pl.when(nz)
             def _():
-                r = row_ref[t]
-                cl = cl_ref[t]
+                r = ibuf[0, t % _IDX_CHUNK]
+                cl = ibuf[1, t % _IDX_CHUNK]
                 cp_t = pltpu.make_async_copy(
-                    tiles_hbm.at[t], tbuf.at[0], sem.at[1]
+                    tiles_hbm.at[t // 2], tbuf, sem.at[1]
                 )
                 cp_k = pltpu.make_async_copy(
-                    keys_hbm.at[r], kbuf.at[0], sem.at[2]
+                    keys_hbm.at[r // 8], kbuf, sem.at[2]
                 )
                 cp_t.start()
                 cp_k.start()
                 cp_t.wait()
                 cp_k.wait()
-                tile = tbuf[0]  # [128, 4] uint32
-                keys = kbuf[0]  # [128] uint32
-                # Frontier bit + group weight per source row, as the
-                # [GROUPS, 128] weighted LHS.  The word select unrolls
-                # over the 4 static frontier words (no in-kernel gather).
+                odd = (t % 2) == 1
+                words = [
+                    jnp.where(odd, tbuf[pl.ds(TILE_WORDS + j, 1), :],
+                              tbuf[pl.ds(j, 1), :])
+                    for j in range(TILE_WORDS)
+                ]  # word j of the tile: (1, 128) over source rows
+                keys = pltpu.bitcast(kbuf[pl.ds(r % 8, 1), :], jnp.int32)
+                # Frontier bit + group weight per source row u, as the
+                # [GROUPS, 128] weighted LHS.
                 u = jax.lax.broadcasted_iota(jnp.int32, (GROUPS, TILE), 1)
                 g = jax.lax.broadcasted_iota(jnp.int32, (GROUPS, TILE), 0)
-                fbit = jnp.zeros((GROUPS, TILE), jnp.uint32)
+                fword = jnp.zeros((GROUPS, TILE), jnp.int32)
                 for j in range(TILE_WORDS):
-                    fbit = jnp.where(
-                        (u >> 5) == j,
-                        (fbuf[0, j] >> (u & 31).astype(jnp.uint32)) & 1,
-                        fbit,
-                    )
-                member = (u >> 4) == g
+                    fword = jnp.where((u >> 5) == j, fw4[j], fword)
+                fbit = jax.lax.shift_right_logical(fword, u & 31) & 1
+                # Mosaic converts to f32 from i32 only (values < 2^16).
                 fw = jnp.where(
-                    member & (fbit == 1),
-                    (jnp.uint32(1) << (u & 15).astype(jnp.uint32)),
-                    jnp.uint32(0),
+                    ((u >> 4) == g) & (fbit == 1), 1 << (u & 15), 0
                 ).astype(jnp.float32)
-                # Tile unpack: [128, 128] 0/1 — word select unrolled over
-                # the 4 static v-words, shifts per lane.
-                vv = jax.lax.broadcasted_iota(jnp.int32, (TILE, TILE), 1)
+                # The transposed tile: at[v, u] = bit v%32 of word v//32
+                # of source row u (destination v on sublanes).
+                vv = jax.lax.broadcasted_iota(jnp.int32, (TILE, TILE), 0)
                 aw = jnp.zeros((TILE, TILE), jnp.uint32)
                 for j in range(TILE_WORDS):
-                    aw = jnp.where((vv >> 5) == j, tile[:, j][:, None], aw)
-                a = ((aw >> (vv & 31).astype(jnp.uint32)) & 1).astype(
-                    jnp.float32
+                    aw = jnp.where((vv >> 5) == j, words[j], aw)
+                at = ((aw >> (vv & 31).astype(jnp.uint32)) & 1).astype(
+                    jnp.int32
+                ).astype(jnp.float32)
+                # THE masked matmul (fw @ at.T): 16-bit contribution masks
+                # per group, exact in f32 (sums of distinct powers of two).
+                m = jax.lax.dot_general(
+                    fw, at, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
                 )
-                # THE masked matmul: 16-bit contribution masks per group,
-                # exact in f32 (sums of distinct powers of two < 2^16).
-                m = jnp.dot(fw, a, preferred_element_type=jnp.float32)
-                masks = m.astype(jnp.uint32)  # [GROUPS, 128]
-                # Reduce each mask to the min ORIGINAL id; accumulate the
-                # column minimum into this tile's output row.
+                masks = m.astype(jnp.int32)  # [GROUPS, 128]
+                # Keys of the source rows on sublanes: transpose the
+                # broadcast key row.
+                keys = jnp.where(keys == -1, imax, keys)
+                kcol = jnp.transpose(jnp.broadcast_to(keys, (TILE, TILE)))
                 ii = jax.lax.broadcasted_iota(jnp.int32, (16, TILE), 0)
-                cand = jnp.full((TILE,), SENT, jnp.uint32)
+                cand = jnp.full((1, TILE), imax, jnp.int32)
                 for gi in range(GROUPS):
-                    bits = (masks[gi][None, :] >> ii.astype(jnp.uint32)) & 1
-                    kg = jax.lax.dynamic_slice_in_dim(keys, gi * 16, 16)
+                    bits = (masks[gi:gi + 1, :] >> ii) & 1
+                    kg = kcol[gi * 16:(gi + 1) * 16, :]
                     cand = jnp.minimum(
                         cand,
-                        jnp.min(
-                            jnp.where(bits == 1, kg[:, None], SENT), axis=0
-                        ),
+                        jnp.min(jnp.where(bits == 1, kg, imax), axis=0,
+                                keepdims=True),
                     )
                 cur = o_ref[pl.ds(cl, 1), :]
-                o_ref[pl.ds(cl, 1), :] = jnp.minimum(cur, cand[None, :])
+                o_ref[pl.ds(cl, 1), :] = jnp.minimum(cur, cand)
 
             return carry
 
         jax.lax.fori_loop(t0, t1, body, jnp.int32(0))
 
     return kernel
+
+
+def _pad_rows(x: jax.Array, multiple: int) -> jax.Array:
+    pad = (-x.shape[0]) % multiple
+    if not pad:
+        return x
+    return jnp.concatenate(
+        [x, jnp.zeros((pad, *x.shape[1:]), x.dtype)], axis=0
+    )
 
 
 # bfs_tpu: hot traced
@@ -369,29 +419,40 @@ def expand_frontier_mxu(
     col_local = (col_id % SB_TILES).astype(jnp.int32)
     fwp = _pad_frontier_words(fwords, rows, rtp)
     fblk = fwp.reshape(-1, TILE_WORDS)[row_idx]  # [ntp, 4]
+    fblk = _pad_rows(fblk, _F_BLOCK).reshape(-1, _F_ROWS, TILE)
+    # Word-major tiles, two per (8, 128) block.
+    tiles_wm = _pad_rows(jnp.swapaxes(tiles, 1, 2), 2).reshape(
+        -1, 2 * TILE_WORDS, TILE
+    )
+    keys_b = _pad_rows(keys2d, 8).reshape(-1, 8, TILE)
+    idx = _pad_rows(jnp.stack([row_idx, col_local], axis=1), _IDX_CHUNK)
+    idx = jnp.swapaxes(idx.reshape(-1, _IDX_CHUNK, 2), 1, 2)
     grid = vtp // SB_VERTS
     out = pl.pallas_call(
         _mxu_kernel_factory(),
         grid=(grid,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),  # sb_indptr
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # col_local
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # row_idx
-            pl.BlockSpec(memory_space=pl.ANY),  # tiles
-            pl.BlockSpec(memory_space=pl.ANY),  # fblk
-            pl.BlockSpec(memory_space=pl.ANY),  # keys2d
+            pl.BlockSpec(memory_space=pl.ANY),  # (row, column) chunks
+            pl.BlockSpec(memory_space=pl.ANY),  # tiles (word-major pairs)
+            pl.BlockSpec(memory_space=pl.ANY),  # frontier word blocks
+            pl.BlockSpec(memory_space=pl.ANY),  # key row blocks
         ],
         out_specs=pl.BlockSpec((SB_TILES, TILE), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((vtp // TILE, TILE), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((vtp // TILE, TILE), jnp.int32),
         scratch_shapes=[
-            pltpu.VMEM((1, TILE, TILE_WORDS), jnp.uint32),  # tile buf
-            pltpu.VMEM((1, TILE_WORDS), jnp.uint32),  # frontier block
-            pltpu.VMEM((1, TILE), jnp.uint32),  # key row
-            pltpu.SemaphoreType.DMA((3,)),
+            pltpu.SMEM((2, _IDX_CHUNK), jnp.int32),  # index chunk
+            pltpu.VMEM((2 * TILE_WORDS, TILE), jnp.uint32),  # tile pair
+            pltpu.VMEM((_F_ROWS, TILE), jnp.uint32),  # frontier block
+            pltpu.VMEM((8, TILE), jnp.uint32),  # key rows
+            pltpu.SemaphoreType.DMA((4,)),
         ],
         interpret=interpret,
-    )(sb_indptr, col_local, row_idx, tiles, fblk, keys2d)
-    return out.reshape(-1)[:cols]
+    )(sb_indptr, idx, tiles_wm, fblk, keys_b)
+    out = out.reshape(-1)[:cols]
+    return jnp.where(
+        out == np.iinfo(np.int32).max, SENT, out.astype(jnp.uint32)
+    )
 
 
 def _expand(st_fwords, tile_ops, geo: tuple, use_kernel: bool):

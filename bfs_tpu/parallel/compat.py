@@ -1,116 +1,62 @@
-"""Version-spanning ``shard_map`` / ``pjit`` compat shim (ROADMAP item 1).
+"""The one place the mesh programs touch JAX's ``shard_map`` surface.
 
-JAX moved the mesh SPMD surface twice across the versions this repo must
-run on:
-
-  * **jax >= 0.6** exposes ``jax.shard_map`` whose manual axes are named
-    POSITIVELY via ``axis_names={...}`` and whose replication transfer
-    uses ``jax.lax.pcast(..., to="varying")``.
-  * **jax 0.4.x** (the harness container pins 0.4.37) has only
-    ``jax.experimental.shard_map.shard_map`` whose manual axes are named
-    NEGATIVELY via ``auto=frozenset(...)`` (axes left automatic), whose
-    replication checker predates ``pcast``, and which rejects the
-    ``axis_names`` kwarg outright — the exact seed-identical 40-test
-    failure tier-1 carried through PRs 1-6.
-
-This module is the ONE translation point: every mesh program in
-:mod:`bfs_tpu.parallel.sharded` (and the mesh tests) calls
-:func:`shard_map` / :func:`pcast_varying` from here instead of touching
-the jax API directly.
-
-Old-API semantics: the sharded programs either communicate over every
-mesh axis they run on or are simply replicated along the unused axis
-(the ``axis_names={GRAPH_AXIS}`` single-source programs never touch
-``batch``), so the old call runs FULLY MANUAL over all mesh axes with
-``check_rep=False`` — the positive/negative axis-naming difference and
-the missing ``pcast`` both disappear: a value that new jax must
-explicitly pcast to "varying" before a ``while_loop`` carry is simply
-not rep-checked on the old path, and an axis absent from an out_spec
-means "replicated along it" under both APIs.
+Every mesh program in :mod:`bfs_tpu.parallel` calls :func:`shard_map` and
+:func:`pcast_varying` from here instead of the jax API directly, so the
+varying-axis rules of the installed JAX are handled in one module.
 """
 
 from __future__ import annotations
 
 import jax
 
-try:  # JAX >= 0.6 exposes shard_map at top level (axis_names API)
-    from jax import shard_map as _shard_map_new
-
-    _HAS_AXIS_NAMES_API = True
-except ImportError:  # pragma: no cover - exercised on jax 0.4.x only
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    _HAS_AXIS_NAMES_API = False
-
-try:  # jax.experimental.pjit is the pre-unification entry point
-    from jax.experimental.pjit import pjit as _pjit
-except ImportError:  # pragma: no cover - pjit folded into jax.jit
-    _pjit = jax.jit
-
-#: ``pjit`` resolved once at import: modern jax unifies it into
-#: ``jax.jit`` (in_shardings/out_shardings kwargs); 0.4.x still ships the
-#: experimental entry point with the same signature.
-pjit = _pjit
-
-
-def has_axis_names_api() -> bool:
-    """True when this jax exposes ``jax.shard_map`` (the axis_names API)."""
-    return _HAS_AXIS_NAMES_API
-
-
-def shard_map_available() -> bool:
-    """True when SOME shard_map exists (it does on every jax this repo
-    supports — kept for symmetric test gating; the mesh tests used to skip
-    on :func:`has_axis_names_api`, which the shim makes unnecessary)."""
-    return True
-
 
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None):
-    """``shard_map`` spanning both APIs.
-
-    ``axis_names`` carries the NEW API's semantics: the set of mesh axes
-    the body is manual over (None = all of them).  On old jax the program
-    runs fully manual over every mesh axis with ``check_rep=False`` — see
-    the module docstring for why that is equivalent for this repo's
-    programs (no partial-auto program exists here; an axis outside
-    ``axis_names`` is never communicated over, only replicated along).
-    """
-    if _HAS_AXIS_NAMES_API:
-        kwargs = {}
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return _shard_map_new(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-        )
-    return _shard_map_old(
-        f,
-        mesh=mesh,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        check_rep=False,
+    """``jax.shard_map``; ``axis_names`` is the set of mesh axes the body
+    is manual over (None = all of them)."""
+    kwargs = {}
+    if axis_names is not None:
+        kwargs["axis_names"] = set(axis_names)
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
     )
 
 
 def pcast_varying(x, axes):
-    """``jax.lax.pcast(x, axes, to="varying")`` where it exists; identity
-    on old jax (whose ``check_rep=False`` path never tracks replication,
-    so there is nothing to cast — the carry/body rep mismatch pcast fixes
-    on new jax cannot arise)."""
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is None:
+    """Mark ``x`` varying over ``axes``.
+
+    ``jax.lax.pcast(..., to="varying")`` refuses an axis the value already
+    varies over, so only the missing ones are cast (``jax.typeof(x).vma``
+    is the set of axes a value varies over inside ``shard_map``)."""
+    missing = tuple(a for a in axes if a not in jax.typeof(x).vma)
+    if not missing:
         return x
-    return pcast(x, tuple(axes), to="varying")
+    return jax.lax.pcast(x, missing, to="varying")
 
 
 def pcast_carry(tree, axes):
     """:func:`pcast_varying` mapped over a pytree of loop-carry leaves.
 
-    The sharded relay's carry grew replicated-initialized leaves whose
-    BODY outputs derive from graph-axis-varying values (the telemetry
-    accumulators fed the all-gathered frontier words, the Beamer
-    ``mu``/``prev`` fed the frontier masses): new jax's replication
-    checker requires the init side of such a ``while_loop`` carry to be
-    cast to "varying" up front, exactly like the frontier words
-    themselves.  Identity on jax 0.4.x (same contract as
-    :func:`pcast_varying`)."""
+    A ``while_loop`` carry leaf initialised replicated whose body output
+    derives from axis-varying values (telemetry accumulators, the Beamer
+    ``mu``/``prev`` frontier masses) must be cast to varying up front."""
     return jax.tree_util.tree_map(lambda x: pcast_varying(x, axes), tree)
+
+
+def unvary(tree, axes):
+    """Make leaves that are equal on every shard but typed varying over
+    ``axes`` invariant, so an out_spec can name them replicated.
+
+    The all-gathered frontier words are the same on every shard, yet
+    ``all_gather`` types them varying, and so is everything derived from
+    them (telemetry, the direction hysteresis). ``pmax`` of equal values
+    is that value; only the axes a leaf varies over are reduced."""
+
+    def one(x):
+        varying = tuple(a for a in axes if a in jax.typeof(x).vma)
+        if not varying:
+            return x
+        if x.dtype == bool:
+            return jax.lax.pmax(x.astype("int32"), varying).astype(bool)
+        return jax.lax.pmax(x, varying)
+
+    return jax.tree_util.tree_map(one, tree)

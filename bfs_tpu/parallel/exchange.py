@@ -72,6 +72,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import knobs
+from .compat import pcast_varying
 
 #: Arm codes, recorded per level in the telemetry exchange-arm
 #: accumulator (0 = level not executed, same convention as the
@@ -450,7 +451,10 @@ def make_grid_row_reduce(cfg: ExchangeConfig, kw: int, nw: int,
                 jnp.int32(EX_DELTA)
 
         def fall(cand):
-            return row_bitmap(cand, own_cj)
+            # pmin leaves the words invariant over row; all_gather in the
+            # list arm does not, and cond's branches must agree.
+            candg, nb, arm = row_bitmap(cand, own_cj)
+            return pcast_varying(candg, (row_axis,)), nb, arm
 
         return jax.lax.cond(fits, lst, fall, cand)
     return row_delta

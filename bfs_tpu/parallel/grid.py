@@ -56,7 +56,7 @@ import numpy as np
 
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .compat import pcast_carry, pcast_varying, shard_map as _shard_map
+from .compat import pcast_carry, pcast_varying, shard_map as _shard_map, unvary
 from .. import knobs
 from ..graph.grid_layout import (
     GRID_KEY_SENTINEL,
@@ -357,7 +357,8 @@ def _bfs_grid_fused(
             .set(jnp.uint32(1) << (source & 31).astype(jnp.uint32))
         )
         fwr = jax.lax.dynamic_slice(gw, (i_idx * c * nw,), (c * nw,))
-        fwr = pcast_varying(fwr, (GRID_ROW_AXIS,))
+        # The body's words vary over both axes; they are equal along col.
+        fwr = pcast_varying(fwr, (GRID_ROW_AXIS, GRID_COL_AXIS))
         # Initial reached-view of C_j: the source bit iff the source
         # block sits in this mesh column.
         sb = source // block
@@ -375,7 +376,7 @@ def _bfs_grid_fused(
                 )
             )
         )
-        rc0 = pcast_varying(rc0, (GRID_COL_AXIS,))
+        rc0 = pcast_varying(rc0, (GRID_ROW_AXIS, GRID_COL_AXIS))
 
         carry = {
             "fw": fwr,
@@ -423,8 +424,11 @@ def _bfs_grid_fused(
         if telemetry:
             return (
                 dist, parent, out["level"], out["changed"],
-                out["occ"], out["dirs"],
-                out["xbc"], out["xac"], out["xbr"], out["xar"],
+                *unvary(
+                    (out["occ"], out["dirs"], out["xbc"], out["xac"],
+                     out["xbr"], out["xar"]),
+                    (GRID_ROW_AXIS, GRID_COL_AXIS),
+                ),
             )
         return dist, parent, out["level"], out["changed"]
 
@@ -494,7 +498,7 @@ def _bfs_grid_segment(
             ex_cfg=ex_cfg,
         )
         c_ = dict(c_)
-        c_["fw"] = pcast_varying(c_["fw"], (GRID_ROW_AXIS,))
+        c_["fw"] = pcast_varying(c_["fw"], (GRID_ROW_AXIS, GRID_COL_AXIS))
         extras = {
             k: c_[k]
             for k in ("mu", "prev", "occ", "dirs", "xbc", "xac",
@@ -506,7 +510,14 @@ def _bfs_grid_segment(
         def cond(c_):
             return cond0(c_) & (c_["level"] < seg_end)
 
-        return jax.lax.while_loop(cond, body, c_)
+        out = jax.lax.while_loop(cond, body, c_)
+        # Every leaf but the per-cell state and reached-views is equal
+        # along the axes its spec replicates it over.
+        return {
+            k: v if k in state_keys or k == "rc"
+            else unvary(v, (GRID_COL_AXIS,) if k == "fw" else both)
+            for k, v in out.items()
+        }
 
     both = (GRID_ROW_AXIS, GRID_COL_AXIS)
     carry_specs = {}

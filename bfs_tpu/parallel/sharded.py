@@ -32,7 +32,7 @@ import numpy as np
 
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .compat import pcast_carry, pcast_varying, shard_map as _shard_map
+from .compat import pcast_carry, pcast_varying, shard_map as _shard_map, unvary
 from .. import knobs
 
 from ..graph.csr import DeviceGraph, Graph, build_device_graph
@@ -706,7 +706,10 @@ def _bfs_sharded_relay_fused(
         if telemetry:
             return (
                 dist, parent, out["level"], out["changed"],
-                out["occ"], out["dirs"], out["xb"], out["xa"],
+                *unvary(
+                    (out["occ"], out["dirs"], out["xb"], out["xa"]),
+                    (GRAPH_AXIS,),
+                ),
             )
         return dist, parent, out["level"], out["changed"]
 
@@ -939,7 +942,11 @@ def _bfs_sharded_relay_segment(
                 )
             return out
 
-        return jax.lax.while_loop(cond, body, c)
+        out = jax.lax.while_loop(cond, body, c)
+        return {
+            k: v if k in state_keys else unvary(v, (GRAPH_AXIS,))
+            for k, v in out.items()
+        }
 
     carry_in_specs = {
         k: (P(GRAPH_AXIS) if k in state_keys else P()) for k in carry
@@ -1782,12 +1789,9 @@ def bfs_sharded(
                        direction_static, ex_cfg.key(), sparse)
                 compiled = _SHARDED_AOT_CACHE.get(key)
                 if compiled is None:
-                    from ..models.bfs import compile_exe_cached
-
-                    compiled = compile_exe_cached(
-                        _bfs_sharded_relay_fused.lower(*args, **kwargs),
-                        RelayEngine._COMPILER_OPTIONS,
-                    )
+                    compiled = _bfs_sharded_relay_fused.lower(
+                        *args, **kwargs
+                    ).compile(compiler_options=RelayEngine._COMPILER_OPTIONS)
                     while len(_SHARDED_AOT_CACHE) >= _SHARDED_AOT_CACHE_MAX:
                         _SHARDED_AOT_CACHE.pop(next(iter(_SHARDED_AOT_CACHE)))
                     _SHARDED_AOT_CACHE[key] = compiled

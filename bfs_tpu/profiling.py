@@ -64,10 +64,10 @@ def state_update_bytes(vr: int, packed: bool) -> dict:
 
 
 def _compile(fn, args, compiler_options):
-    from .models.bfs import compile_exe_cached
-
     opts = compiler_options if jax.default_backend() == "tpu" else None
-    return compile_exe_cached(jax.jit(fn).lower(jnp.int32(1), *args), opts)
+    return jax.jit(fn).lower(jnp.int32(1), *args).compile(
+        compiler_options=opts
+    )
 
 
 def _sync(result):

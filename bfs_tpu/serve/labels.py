@@ -163,12 +163,14 @@ def build_label_index(
     k: int,
     *,
     engine: str = "pull",
+    layout=None,
     chunk: int = DEFAULT_CHUNK,
     ckpt_dir: str | os.PathLike | None = None,
     verify_rows: int = 2,
 ) -> LabelIndex:
     """Sweep K landmark roots through the multi-source engine and pack the
-    forests into a :class:`LabelIndex`.
+    forests into a :class:`LabelIndex`.  ``layout`` is a prebuilt
+    ``engine`` layout of ``graph`` (the serve registry's); None builds one.
 
     The sweep runs in ``chunk``-root slices; when superstep checkpointing
     is on (``BFS_TPU_CKPT``), every finished slice is saved as a durable
@@ -217,7 +219,9 @@ def build_label_index(
 
     for ci in range(start, -(-kk // chunk)):
         roots = landmarks[ci * chunk : (ci + 1) * chunk]
-        res = bfs_multi(graph, roots, engine=engine)
+        res = bfs_multi(
+            graph if layout is None else layout, roots, engine=engine
+        )
         d = np.asarray(res.dist)
         reach = d != INF_DIST
         if reach.any() and int(d[reach].max()) >= LABEL_INF:
@@ -406,6 +410,7 @@ def build_label_oracle(
     *,
     cache=None,
     engine: str = "pull",
+    layout=None,
     ckpt_dir: str | os.PathLike | None = None,
 ):
     """``(LabelOracle, info)`` — the server's register-time entry point:
@@ -417,7 +422,8 @@ def build_label_oracle(
 
     t0 = time.perf_counter()
     idx, info = load_or_build_labels(
-        graph, k, cache=cache, engine=engine, ckpt_dir=ckpt_dir
+        graph, k, cache=cache, engine=engine, layout=layout,
+        ckpt_dir=ckpt_dir,
     )
     oracle = LabelOracle(idx, budget_bytes=labels_budget_bytes())
     info = dict(info)

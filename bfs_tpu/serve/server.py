@@ -373,8 +373,13 @@ class BfsServer:
         from .labels import LabelBudgetError, build_label_oracle
 
         try:
+            # The sweep runs on the server's engine and its registered
+            # layout: a second layout build per graph would double the
+            # register cost (a host relay build at scale is minutes).
+            engine = self.default_engine
             oracle, info = build_label_oracle(
-                rec.graph, k, cache=self.registry.layout_cache
+                rec.graph, k, cache=self.registry.layout_cache,
+                engine=engine, layout=self.registry.layout(rec.name, engine),
             )
         except LabelBudgetError as exc:
             logger.warning("label index over budget: %s", exc)

@@ -34,8 +34,8 @@ _artifact_counters: dict[str, int] = {}  # guarded-by: _artifact_lock
 
 
 def bump_artifact(name: str, by: int = 1) -> None:
-    """Count one artifact-cache event (e.g. ``layout_cache_hits``,
-    ``exe_cache_misses``).  Thread-safe, process-global."""
+    """Count one artifact-cache event (e.g. ``layout_cache_hits``).
+    Thread-safe, process-global."""
     with _artifact_lock:
         _artifact_counters[name] = _artifact_counters.get(name, 0) + by
 
@@ -45,7 +45,7 @@ def artifact_report() -> dict:
     (``None`` when a cache saw no traffic this process)."""
     with _artifact_lock:
         out: dict = dict(_artifact_counters)
-    for cache in ("layout_cache", "exe_cache"):
+    for cache in ("layout_cache",):
         h, m = out.get(f"{cache}_hits", 0), out.get(f"{cache}_misses", 0)
         out[f"{cache}_hit_rate"] = h / (h + m) if h + m else None
     return out

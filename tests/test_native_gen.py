@@ -1,5 +1,7 @@
 """Native data-loader (native/graph_gen.cpp) vs the NumPy fallbacks."""
 
+import os
+
 import numpy as np
 import pytest
 
@@ -111,3 +113,36 @@ def test_pad_identity_native_identity_first():
         assert perm[j] == j
     for j in range(1100, n):
         assert perm[j] == j
+
+
+def test_native_lib_is_keyed_on_source(tmp_path):
+    """A library is built under a name keyed on its source and recipe: an
+    unkeyed or stale ``.so`` beside it is never loaded, and an edited
+    source builds a new library."""
+    from bfs_tpu.utils.native_loader import NativeLib
+
+    src = tmp_path / "x.cpp"
+    src.write_text('extern "C" int f() { return 1; }\n')
+    stale = tmp_path / "build" / "libx.so"
+    stale.parent.mkdir()
+    stale.write_bytes(b"not a shared library")
+
+    lib = NativeLib(str(src), str(stale), register=lambda lib: None)
+    path = lib.so_path()
+    assert path != str(stale)
+    assert lib.require() is not None and os.path.exists(path)
+    src.write_text('extern "C" int f() { return 2; }\n')
+    edited = NativeLib(str(src), str(stale), register=lambda lib: None)
+    assert edited.so_path() != path
+
+
+def test_native_lib_build_failure_raises_with_reason(tmp_path):
+    from bfs_tpu.utils.native_loader import NativeLib
+
+    src = tmp_path / "bad.cpp"
+    src.write_text("this is not C++\n")
+    lib = NativeLib(str(src), str(tmp_path / "libbad.so"),
+                    register=lambda lib: None)
+    assert lib.load() is None
+    with pytest.raises(RuntimeError, match="failed"):
+        lib.require()

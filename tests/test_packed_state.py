@@ -323,20 +323,7 @@ def test_sharded_relay_packed_parity(num_shards):
             np.testing.assert_array_equal(p, p_o)
 
 
-def _mesh_relay_available() -> bool:
-    """The shard_map relay program runs through the version-spanning shim
-    (bfs_tpu/parallel/compat.py) on every supported jax — the old
-    jax.shard_map axis_names gate is retired with it."""
-    from bfs_tpu.parallel.compat import shard_map_available
-
-    return shard_map_available()
-
-
 @needs_native
-@pytest.mark.skipif(
-    not _mesh_relay_available(),
-    reason="jax.shard_map (axis_names API) unavailable",
-)
 @pytest.mark.parametrize("num_shards", [1, 2, 8])
 def test_sharded_relay_packed_parity_on_mesh(num_shards):
     """The real shard_map program on the virtual CPU mesh (runs where the

@@ -1,15 +1,14 @@
 """Pre-build the bench matrix's persistent artifacts so a driver run is
 warm from its first second (ISSUE 2 satellite).
 
-For every scale in the matrix (BENCH_SCALE + BENCH_FALLBACK_SCALES by
-default) this builds-or-loads, in order:
+For every scale in the matrix (default: BENCH_SCALE) this
+builds-or-loads, in order:
 
   1. the device-ready R-MAT graph npz (bench.load_or_build);
   2. the relay layout bundle (content-addressed, memmap-loadable —
      bfs_tpu/cache/layout.py) and, with --pull, the ELL pull bundle;
   3. with --compile (TPU backends only), the fused single-source relay
-     program, populating the serialized-executable cache the bench loads
-     from (models/bfs.py compile_exe_cached).
+     program, populating JAX's persistent compile cache.
 
 Each step prints its warm/cold status and timing; the final line is the
 artifact-cache counter report.  Run it once per (machine, cache dir) —
@@ -83,8 +82,7 @@ def main(argv=None) -> int:
     ap.add_argument(
         "--scales",
         default=None,
-        help="comma-separated R-MAT scales (default: BENCH_SCALE + "
-        "BENCH_FALLBACK_SCALES, i.e. the bench matrix)",
+        help="comma-separated R-MAT scales (default: BENCH_SCALE)",
     )
     ap.add_argument("--edge-factor", type=int,
                     default=int(os.environ.get("BENCH_EDGE_FACTOR", "6")))
@@ -109,7 +107,7 @@ def main(argv=None) -> int:
                     "BFS_TPU_LABELS knob, or 32 when that is off)")
     ap.add_argument("--compile", action="store_true",
                     help="also AOT-compile the fused relay program per "
-                    "scale (TPU backends; populates the exe cache)")
+                    "scale (TPU backends; populates the compile cache)")
     ap.add_argument("--builder", choices=("auto", "device", "host"),
                     default="auto",
                     help="relay layout builder flavor for cold builds "
@@ -127,11 +125,7 @@ def main(argv=None) -> int:
     if args.scales:
         scales = [int(s) for s in args.scales.split(",") if s.strip()]
     else:
-        scales = [int(os.environ.get("BENCH_SCALE", "24"))] + [
-            int(s)
-            for s in os.environ.get("BENCH_FALLBACK_SCALES", "22,20").split(",")
-            if s.strip()
-        ]
+        scales = [int(os.environ.get("BENCH_SCALE", "24"))]
     scales = sorted(set(scales), reverse=True)
 
     import jax
@@ -252,18 +246,15 @@ def main(argv=None) -> int:
             if jax.default_backend() != "tpu":
                 print(
                     f"s{scale}: --compile skipped (backend is "
-                    f"{jax.default_backend()}, exe cache is TPU-only)",
+                    f"{jax.default_backend()}, the Pallas programs are TPU-only)",
                     flush=True,
                 )
             else:
                 from bfs_tpu.models.bfs import RelayEngine
 
-                from bfs_tpu.bench import _mark_exe_warm
-
                 t0 = time.perf_counter()
                 eng = RelayEngine(rg, sparse_hybrid=False)
                 _ = int(eng.run_many_device([source])[-1].level)
-                _mark_exe_warm(key)
                 print(
                     f"s{scale}: fused program compiled + warm in "
                     f"{time.perf_counter() - t0:.1f}s "

@@ -1,8 +1,10 @@
 """Round-3 device microbench: program overhead, gather/scatter rates."""
-import os, time
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/root/repo/.bench_cache/xla")
+import os, sys, time
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax, jax.numpy as jnp, numpy as np
-jax.config.update("jax_compilation_cache_dir", "/root/repo/.bench_cache/xla")
+from bfs_tpu.config import enable_compile_cache
+
+enable_compile_cache()
 dev = jax.devices()[0]
 print("device", dev)
 

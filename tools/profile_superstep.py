@@ -10,11 +10,9 @@ import json
 import os
 import sys
 
-sys.path.insert(0, "/root/repo")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 
-jax.config.update("jax_compilation_cache_dir", "/root/repo/.bench_cache/xla")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 5.0)
 
 from bfs_tpu.bench import load_or_build, load_or_build_relay
 from bfs_tpu.models.bfs import RelayEngine
