@@ -335,7 +335,7 @@ def benes_elem_windows(pass_static_info, array_rows: list, n: int) -> list:
 
     windows: list[Window] = []
     r = n // LANES
-    for ai, (mode, tr, tt, specs) in enumerate(pass_static_info):
+    for ai, (mode, tr, tt, specs, m) in enumerate(pass_static_info):
         main_rows = array_rows[ai]
         if mode == "local":
             for pid in range(r // tr):
@@ -345,10 +345,9 @@ def benes_elem_windows(pass_static_info, array_rows: list, n: int) -> list:
                         f"elem-local:d{st.d}:p{pid}",
                         st.offset // LANES + pid * mrows, mrows, main_rows,
                     ))
-        else:  # outer
-            span = (r // tr) // 2
-            mrows = span * (tt // 32)
-            for pid in range(tr // tt):
+        else:  # outer: grid (hi, tr/tt), one (m/2, tt/32) block per step
+            mrows = (m // 2) * (tt // 32)
+            for pid in range((r // (tr * m)) * (tr // tt)):
                 for st in specs:
                     windows.append(Window(
                         f"elem-outer:d{st.d}:p{pid}",

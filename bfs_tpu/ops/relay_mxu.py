@@ -75,6 +75,7 @@ __all__ = [
     "expand_frontier_mxu",
     "expand_frontier_mxu_xla",
     "mxu_device_operands",
+    "mxu_operand_arrays",
     "mxu_superstep_packed",
     "mxu_superstep",
 ]
@@ -156,18 +157,70 @@ def tiles_budget_bytes() -> int:
     return int(knobs.get("BFS_TPU_MXU_TILE_GB") * (1 << 30))
 
 
+#: Tiles per twin ``lax.map`` slab; shipped tile counts are padded to it.
+TWIN_CHUNK = 256
+
+#: Tiles whose (row, column) indices are DMA'd into SMEM at a time: the
+#: whole [ntp] index arrays outgrow the 1 MiB SMEM past ~100k tiles.
+_IDX_CHUNK = 1024
+
+#: Tiles per frontier-word DMA block: the [ntp, 4] per-tile frontier
+#: words travel lane-dense as [ntp/32, 128] rows, DMA'd 8 rows at a time.
+_F_ROWS = 8
+_F_BLOCK = _F_ROWS * TILE // TILE_WORDS  # 256 tiles per (8, 128) block
+
+
+def mxu_operand_arrays(tiles, row_idx, col_id, sb_indptr, keys2d, *,
+                       rtp: int, vtp: int) -> tuple:
+    """Host-side, once per layout: the tile-operand tuple ``(tiles_wm,
+    row_idx, col_id, sb_indptr, keys_b, idx)`` in the layout both the
+    kernel and the twin read, so no call re-lays out the tiles.
+
+    - ``tiles_wm``: tiles word-major in pairs, ``[ntp/2, 8, 128]`` (row
+      ``4*(t&1)+j`` = word ``j`` of tile ``t`` over its 128 source rows),
+      padded with inert tiles to a multiple of :data:`TWIN_CHUNK`;
+    - ``row_idx`` / ``col_id``: per tile, padded likewise (the frontier's
+      zero pad block / the dropped overflow column segment);
+    - ``keys_b``: the key table in 8-row blocks, ``[-1, 8, 128]``;
+    - ``idx``: (row, superblock-local column) per tile in SMEM-sized
+      chunks, ``[-1, 2, _IDX_CHUNK]``."""
+    k = (-tiles.shape[0]) % TWIN_CHUNK
+    if k:
+        tiles = np.concatenate(
+            [tiles, np.zeros((k, TILE, TILE_WORDS), np.uint32)]
+        )
+        row_idx = np.concatenate([row_idx, np.full(k, rtp // TILE, np.int32)])
+        col_id = np.concatenate([col_id, np.full(k, vtp // TILE, np.int32)])
+    tiles_wm = np.ascontiguousarray(np.swapaxes(tiles, 1, 2)).reshape(
+        -1, 2 * TILE_WORDS, TILE
+    )
+    keys = np.asarray(keys2d, np.uint32)
+    keys = np.concatenate(
+        [keys, np.zeros(((-keys.shape[0]) % 8, TILE), np.uint32)]
+    ).reshape(-1, 8, TILE)
+    idx = np.stack([row_idx, col_id % SB_TILES], axis=1).astype(np.int32)
+    idx = np.concatenate(
+        [idx, np.zeros(((-idx.shape[0]) % _IDX_CHUNK, 2), np.int32)]
+    )
+    idx = np.ascontiguousarray(
+        np.swapaxes(idx.reshape(-1, _IDX_CHUNK, 2), 1, 2)
+    )
+    return (tiles_wm, np.asarray(row_idx, np.int32),
+            np.asarray(col_id, np.int32), np.asarray(sb_indptr, np.int32),
+            keys, idx)
+
+
 def mxu_device_operands(at) -> tuple:
     """Ship an :class:`~bfs_tpu.graph.adj_tiles.AdjTiles` layout as the
-    fused programs' tile-operand tuple ``(tiles, row_idx, col_id,
-    sb_indptr, keys2d)`` — the one pytree both the kernel and the twin
-    consume (static geometry travels separately via
-    :func:`mxu_static`)."""
-    return (
-        jnp.asarray(at.tiles),
-        jnp.asarray(at.row_idx),
-        jnp.asarray(at.col_id),
-        jnp.asarray(at.sb_indptr),
-        jnp.asarray(at.keys2d),
+    fused programs' tile-operand tuple (:func:`mxu_operand_arrays`) — the
+    one pytree both the kernel and the twin consume (static geometry
+    travels separately via :func:`mxu_static`)."""
+    return tuple(
+        jnp.asarray(a)
+        for a in mxu_operand_arrays(
+            at.tiles, at.row_idx, at.col_id, at.sb_indptr, at.keys2d,
+            rtp=at.rtp, vtp=at.vtp,
+        )
     )
 
 
@@ -190,59 +243,47 @@ def _pad_frontier_words(fwords: jax.Array, rows: int, rtp: int) -> jax.Array:
 # bfs_tpu: hot traced
 def expand_frontier_mxu_xla(
     fwords: jax.Array, tile_ops: tuple, *, rows: int, cols: int, rtp: int,
-    vtp: int, chunk: int = 256,
+    vtp: int,
 ) -> jax.Array:
     """Bit-identical XLA twin of :func:`expand_frontier_mxu`:
     ``uint32[cols]`` min-original-id candidate per destination
     (``SENT`` where no frontier in-neighbor).  Tiles stream in
-    ``chunk``-sized slabs through ``lax.map`` so the unpacked
-    (chunk, 128, 128) contribution tensor never scales with the graph;
+    :data:`TWIN_CHUNK`-sized slabs through ``lax.map`` so the unpacked
+    (chunk, 4, 128, 32) contribution tensor never scales with the graph;
     uint32 min is exact and order-free, so chunking cannot perturb a
     bit."""
-    tiles, row_idx, col_id, _sb, keys2d = tile_ops
-    ntp = tiles.shape[0]
-    nc = -(-ntp // chunk)
-    npad = nc * chunk - ntp
-    if npad:
-        # Inert padding: the zero frontier pad block + the dropped
-        # overflow column segment (graph/adj_tiles padding convention).
-        tiles = jnp.concatenate(
-            [tiles, jnp.zeros((npad, TILE, TILE_WORDS), jnp.uint32)]
-        )
-        row_idx = jnp.concatenate(
-            [row_idx, jnp.full(npad, rtp // TILE, jnp.int32)]
-        )
-        col_id = jnp.concatenate(
-            [col_id, jnp.full(npad, vtp // TILE, jnp.int32)]
-        )
+    tiles_wm, row_idx, col_id, _sb, keys_b, _idx = tile_ops
+    ntp = row_idx.shape[0]
+    nc = ntp // TWIN_CHUNK
+    keys2d = keys_b.reshape(-1, TILE)
     fwp = _pad_frontier_words(fwords, rows, rtp)
     fblk = fwp.reshape(-1, TILE_WORDS)[row_idx]  # [ntp, 4]
     shifts = jnp.arange(32, dtype=jnp.uint32)
 
     def per_chunk(args):
-        tk, fb, rk = args
+        tk, fb, rk = args  # tk: [chunk, 4, 128] word-major
         lane = jnp.arange(TILE, dtype=jnp.int32)
         fbits = (fb[:, lane >> 5] >> (lane & 31).astype(jnp.uint32)) & 1
         rowmask = jnp.uint32(0) - fbits  # 0 / ~0 per (tile, u)
-        contrib = tk & rowmask[:, :, None]  # [chunk, 128, 4]
-        bits = (contrib[:, :, :, None] >> shifts) & 1  # [chunk,128,4,32]
+        contrib = tk & rowmask[:, None, :]  # [chunk, 4, 128]
+        bits = (contrib[:, :, :, None] >> shifts) & 1  # [chunk,4,128,32]
         keyrow = keys2d[rk]  # [chunk, 128]
         cand = jnp.min(
             jnp.where(
                 bits != 0,
-                keyrow[:, :, None, None],
+                keyrow[:, None, :, None],
                 SENT,
             ),
-            axis=1,
+            axis=2,
         )  # [chunk, 4, 32]
         return cand.reshape(-1, TILE)
 
     cands = jax.lax.map(
         per_chunk,
         (
-            tiles.reshape(nc, chunk, TILE, TILE_WORDS),
-            fblk.reshape(nc, chunk, TILE_WORDS),
-            row_idx.reshape(nc, chunk),
+            tiles_wm.reshape(nc, TWIN_CHUNK, TILE_WORDS, TILE),
+            fblk.reshape(nc, TWIN_CHUNK, TILE_WORDS),
+            row_idx.reshape(nc, TWIN_CHUNK),
         ),
     ).reshape(-1, TILE)
     out = jax.ops.segment_min(
@@ -250,16 +291,6 @@ def expand_frontier_mxu_xla(
         indices_are_sorted=False,
     )[: vtp // TILE]
     return out.reshape(-1)[:cols]
-
-
-#: Tiles whose (row, column) indices are DMA'd into SMEM at a time: the
-#: whole [ntp] index arrays outgrow the 1 MiB SMEM past ~100k tiles.
-_IDX_CHUNK = 1024
-
-#: Tiles per frontier-word DMA block: the [ntp, 4] per-tile frontier
-#: words travel lane-dense as [ntp/32, 128] rows, DMA'd 8 rows at a time.
-_F_ROWS = 8
-_F_BLOCK = _F_ROWS * TILE // TILE_WORDS  # 256 tiles per (8, 128) block
 
 
 def _mxu_kernel_factory():
@@ -415,18 +446,10 @@ def expand_frontier_mxu(
         from .relay_pallas import pallas_interpret
 
         interpret = pallas_interpret()
-    tiles, row_idx, col_id, sb_indptr, keys2d = tile_ops
-    col_local = (col_id % SB_TILES).astype(jnp.int32)
+    tiles_wm, row_idx, _col_id, sb_indptr, keys_b, idx = tile_ops
     fwp = _pad_frontier_words(fwords, rows, rtp)
     fblk = fwp.reshape(-1, TILE_WORDS)[row_idx]  # [ntp, 4]
     fblk = _pad_rows(fblk, _F_BLOCK).reshape(-1, _F_ROWS, TILE)
-    # Word-major tiles, two per (8, 128) block.
-    tiles_wm = _pad_rows(jnp.swapaxes(tiles, 1, 2), 2).reshape(
-        -1, 2 * TILE_WORDS, TILE
-    )
-    keys_b = _pad_rows(keys2d, 8).reshape(-1, 8, TILE)
-    idx = _pad_rows(jnp.stack([row_idx, col_local], axis=1), _IDX_CHUNK)
-    idx = jnp.swapaxes(idx.reshape(-1, _IDX_CHUNK, 2), 1, 2)
     grid = vtp // SB_VERTS
     out = pl.pallas_call(
         _mxu_kernel_factory(),

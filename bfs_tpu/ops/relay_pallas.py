@@ -33,8 +33,10 @@ from .. import knobs
 from ..graph.relay import StageSpec
 
 LANES = 128
-#: pass-B tile rows: 2048 rows * 128 lanes * 4 B = 1 MB of VMEM for x.
-#: Env-tunable for on-chip sweeps (tools/profile_net_kernel.py).
+#: pass-B tile rows: 256 rows * 128 lanes * 4 B = 128 KB of VMEM for x.
+#: Mosaic unrolls each stage over the tile's vregs, so cold compile time
+#: grows steeply with the tile (PERF.md, PR 21).  Env-tunable for on-chip
+#: sweeps (tools/profile_net_kernel.py).
 TILE_ROWS = knobs.get("BFS_TPU_TILE_ROWS")
 #: outer-pass inner-chunk rows; the x block is (B, OUTER_TT, 128).
 OUTER_TT = knobs.get("BFS_TPU_OUTER_TT")
@@ -611,47 +613,76 @@ def _run_pass(x, arr2d, mode, tr, tt, specs, n, interpret, vma=None,
 # elements (32R + j, l) — so the in-kernel bit->select expansion is one
 # sublane broadcast plus a per-row variable shift, no lane shuffles.
 
-#: element-mode pass-B tile rows: (1, TILE_ROWS_E, 128) uint32 elements —
-#: 4 MB in + 4 MB out under the raised 64 MB scoped-vmem budget.  Element
-#: rows are 32x more numerous than word rows, so tree GROUPS run through
-#: the passes sequentially (G=1 per pallas call); at net 2^28 this keeps
-#: the outer span at 256 blocks instead of the 2048 that OOMed VMEM.
-TILE_ROWS_E = 8192
+#: element-mode local-pass tile rows: (G, TILE_ROWS_E, 128) uint32
+#: elements.  Mosaic unrolls a stage over every vreg of the tile, so cold
+#: compile time grows with the tile: a 8192-row local pass did not compile
+#: for a v5e within 390 s, 256 rows compiles in seconds (PERF.md, PR 21).
+TILE_ROWS_E = 256
+#: outer-pass chunk rows: an outer block is (G, 2^bits, OUTER_TT_E, 128).
 OUTER_TT_E = 64
+#: most outer (block-distance) stages one outer pass fuses: its block is
+#: (G, 2^OUTER_BITS_E, OUTER_TT_E, 128) = 8 MB at G=1, the word mode's
+#: outer block at s22.  Outer stages beyond this window take further
+#: passes, each strided over the block axis, so VMEM stays bounded at any
+#: net size (tree groups still run through the passes one at a time).
+OUTER_BITS_E = 8
 
 
 def elem_pass_static(
     table: tuple[StageSpec, ...], n: int,
     tile_rows: int = TILE_ROWS_E, outer_tt: int = OUTER_TT_E,
+    outer_bits: int = OUTER_BITS_E,
 ):
-    """Pass split for element-major mode (element rows of 128; local run is
-    d < tile_rows*128).  Mirrors :func:`prepare_elem_pass_masks`."""
+    """Pass split for element-major mode: ``((mode, tr, tt, specs, m),
+    ...)`` in execution order.  The local pass fuses the stages with
+    d < tile_rows*128 on (tr, 128) tiles (m = 1).  The outer stages above
+    it are grouped, in table order, into windows of at most ``outer_bits``
+    consecutive block bits; an outer pass views x as (hi, m, tr, 128)
+    with m = 2^window blocks of tr rows (tr = tile rows * 2^lowest bit)
+    and fuses the window on (m, tt, 128) blocks.  Mirrors
+    :func:`prepare_elem_pass_masks`."""
     r = n // LANES
-    tr = min(tile_rows, max(r, 1))
-    local = [i for i, st in enumerate(table) if st.d < tr * LANES]
+    t0 = min(tile_rows, max(r, 1))
+    local = [i for i, st in enumerate(table) if st.d < t0 * LANES]
     assert local and local == list(
         range(local[0], local[-1] + 1)
     ), "local stages must be consecutive"
     lo, hi = local[0], local[-1] + 1
-    tt = min(outer_tt, tr)
-    out = []
 
-    def seg(idx, mode):
-        specs = []
-        off = 0
+    def block_bit(st):
+        return (st.d // (t0 * LANES)).bit_length() - 1
+
+    def specs_of(idx):
+        specs, off = [], 0
         for i in idx:
             st = table[i]
-            nw = st.nwords
-            specs.append(st._replace(offset=off, nwords=nw, lo=0, hi=nw))
-            off += nw
-        return (mode, tr, tt, tuple(specs))
+            specs.append(st._replace(offset=off, lo=0, hi=st.nwords))
+            off += st.nwords
+        return tuple(specs)
 
-    if lo > 0:
-        out.append(seg(list(range(lo)), "outer"))
-    out.append(seg(list(range(lo, hi)), "local"))
+    def outer(idx):
+        out, run = [], []
+        for i in idx:
+            bits = [block_bit(table[j]) for j in run + [i]]
+            if run and max(bits) - min(bits) >= outer_bits:
+                out.append(run)
+                run = []
+            run.append(i)
+        out.append(run)
+        passes = []
+        for run in out:
+            bits = [block_bit(table[j]) for j in run]
+            tr = t0 << min(bits)
+            m = 1 << (max(bits) - min(bits) + 1)
+            tt = min(outer_tt, tr)
+            passes.append(("outer", tr, tt, specs_of(run), m))
+        return passes
+
+    ps = outer(range(lo)) if lo > 0 else []
+    ps.append(("local", t0, min(outer_tt, t0), specs_of(range(lo, hi)), 1))
     if hi < len(table):
-        out.append(seg(list(range(hi, len(table))), "outer"))
-    return tuple(out)
+        ps += outer(range(hi, len(table)))
+    return tuple(ps)
 
 
 def _vertical_repack(words: np.ndarray, nelem: int) -> np.ndarray:
@@ -671,33 +702,30 @@ def _vertical_repack(words: np.ndarray, nelem: int) -> np.ndarray:
 def prepare_elem_pass_masks(
     masks_flat: np.ndarray, table: tuple[StageSpec, ...], n: int,
     tile_rows: int = TILE_ROWS_E, outer_tt: int = OUTER_TT_E,
+    outer_bits: int = OUTER_BITS_E,
 ):
     """Host-side (cached by engines): per-pass vertically-packed mask arrays
-    for element-major mode.  Outer stages additionally re-chunk to
-    (tr/tt, span, tt_rows...) order so each DMA is contiguous, mirroring
+    for element-major mode.  An outer stage's pairs index (hi, m/2, tr)
+    rows; they are re-chunked to (hi, tr/tt, m/2, tt) order so each grid
+    step's mask block is one contiguous DMA, mirroring
     :func:`prepare_pass_masks`."""
-    ps = elem_pass_static(table, n, tile_rows, outer_tt)
     r = n // LANES
-    tr = min(tile_rows, max(r, 1))
-    b = r // tr
-    tt = min(outer_tt, tr)
-    # map pass-local specs back to the original global stages in order
     arrays = []
     gi = 0
-    for mode, _tr, _tt, specs in ps:
+    for mode, tr, tt, specs, m in elem_pass_static(
+        table, n, tile_rows, outer_tt, outer_bits
+    ):
         parts = []
-        for st_local in specs:
+        for _ in specs:
             st = table[gi]
             gi += 1
             w = masks_flat[st.offset : st.offset + st.nwords]
             wv = _vertical_repack(w, st.nwords * 32)
-            if mode == "outer":
-                # stage rows (in vertical-packed units of 32 elem rows):
-                # (span, tr/32, LANES) -> chunk-major (tr/tt, span, tt/32, L)
-                span = b // 2  # outer stages are compact
+            if mode == "outer":  # outer stages are pair-compact
+                nhi = r // (tr * m)
                 wv = (
-                    wv.reshape(span, tr // 32 // (tt // 32), tt // 32, LANES)
-                    .swapaxes(0, 1)
+                    wv.reshape(nhi, m // 2, tr // tt, tt // 32, LANES)
+                    .swapaxes(1, 2)
                     .reshape(-1)
                 )
             parts.append(wv)
@@ -758,13 +786,12 @@ def _elem_stage_outer(x, sel, st: StageSpec, tr: int):
     return jnp.stack([lo ^ t, hi ^ t], axis=2).reshape(x.shape)
 
 
-def _run_elem_pass(x, arr2d, mode, tr, tt, specs, n, interpret):
+def _run_elem_pass(x, arr2d, mode, tr, tt, specs, m, n, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     g = x.shape[0]
     r = n // LANES
-    b = r // tr
 
     if mode == "local":
         grid = (r // tr,)
@@ -789,12 +816,20 @@ def _run_elem_pass(x, arr2d, mode, tr, tt, specs, n, interpret):
             )
             return _elem_stage_local(xv, sel, st, interpret)
 
+        def program_id():
+            return pl.program_id(0)
+
         buf_rows = tr // 32
     else:
-        span = b // 2
-        grid = (tr // tt,)
-        x_view = x.reshape(g, b, tr, LANES)
-        x_spec = pl.BlockSpec((g, b, tt, LANES), lambda j: (0, 0, j, 0))
+        # x as (hi, m, tr, 128): the pass's block bits are the m axis; the
+        # hi blocks above and the tr rows below are batch.
+        span = m // 2
+        nhi = r // (tr * m)
+        grid = (nhi, tr // tt)
+        x_view = x.reshape(g, nhi, m, tr, LANES)
+        x_spec = pl.BlockSpec(
+            (g, 1, m, tt, LANES), lambda h, j: (0, h, 0, j, 0)
+        )
 
         def stage_mrows(st):
             return span * (tt // 32)
@@ -811,7 +846,12 @@ def _run_elem_pass(x, arr2d, mode, tr, tt, specs, n, interpret):
             sel = _expand_vertical(
                 mbuf[slot].reshape(mrows, LANES), mrows * 32, interpret
             ).reshape(span, tt, LANES)
-            return _elem_stage_outer(xv, sel, st, tr)
+            return _elem_stage_outer(
+                xv.reshape(g, m, tt, LANES), sel, st, tr
+            ).reshape(xv.shape)
+
+        def program_id():
+            return pl.program_id(0) * (tr // tt) + pl.program_id(1)
 
         buf_rows = span * (tt // 32)
 
@@ -819,7 +859,7 @@ def _run_elem_pass(x, arr2d, mode, tr, tt, specs, n, interpret):
 
     # bfs_tpu: hot
     def kernel(x_ref, m_hbm, o_ref, mbuf, sem):
-        pid = pl.program_id(0)
+        pid = program_id()
         xv = x_ref[...]
         n_st = len(specs)
         for w in range(min(depth - 1, n_st)):
@@ -854,8 +894,8 @@ def apply_benes_elem_fused(
     interpret: bool = False,
 ) -> jax.Array:
     """Element-major routed Beneš network in fused passes: x uint32[G, n]."""
-    for (mode, tr, tt, specs), arr in zip(pass_static_info, pass_arrays):
-        x = _run_elem_pass(x, arr, mode, tr, tt, specs, n, interpret)
+    for (mode, tr, tt, specs, m), arr in zip(pass_static_info, pass_arrays):
+        x = _run_elem_pass(x, arr, mode, tr, tt, specs, m, n, interpret)
     return x
 
 

@@ -8,6 +8,7 @@ varying-axis rules of the installed JAX are handled in one module.
 from __future__ import annotations
 
 import jax
+import numpy as np
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None):
@@ -42,6 +43,20 @@ def pcast_carry(tree, axes):
     return jax.tree_util.tree_map(lambda x: pcast_varying(x, axes), tree)
 
 
+#: Debug switch (set by tests): :func:`unvary` then also checks, when the
+#: program runs, that every leaf it replicates is equal on every shard,
+#: and raises if not.  Off, a diverging leaf would be silently maxed.
+CHECK_REPLICATED = False
+
+
+def _assert_replicated(lo, hi):
+    for a, b in zip(lo, hi):
+        if not np.array_equal(a, b):
+            raise AssertionError(
+                "unvary: a leaf typed replicated differs across shards"
+            )
+
+
 def unvary(tree, axes):
     """Make leaves that are equal on every shard but typed varying over
     ``axes`` invariant, so an out_spec can name them replicated.
@@ -49,14 +64,22 @@ def unvary(tree, axes):
     The all-gathered frontier words are the same on every shard, yet
     ``all_gather`` types them varying, and so is everything derived from
     them (telemetry, the direction hysteresis). ``pmax`` of equal values
-    is that value; only the axes a leaf varies over are reduced."""
+    is that value; only the axes a leaf varies over are reduced.  With
+    :data:`CHECK_REPLICATED` set, ``pmin`` must agree with it."""
+    lows, highs = [], []
 
     def one(x):
         varying = tuple(a for a in axes if a in jax.typeof(x).vma)
         if not varying:
             return x
-        if x.dtype == bool:
-            return jax.lax.pmax(x.astype("int32"), varying).astype(bool)
-        return jax.lax.pmax(x, varying)
+        v = x.astype("int32") if x.dtype == bool else x
+        hi = jax.lax.pmax(v, varying)
+        if CHECK_REPLICATED:
+            lows.append(jax.lax.pmin(v, varying))
+            highs.append(hi)
+        return hi.astype(bool) if x.dtype == bool else hi
 
-    return jax.tree_util.tree_map(one, tree)
+    out = jax.tree_util.tree_map(one, tree)
+    if lows:
+        jax.debug.callback(_assert_replicated, lows, highs)
+    return out

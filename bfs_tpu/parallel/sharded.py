@@ -292,17 +292,9 @@ def _mxu_candidates_shard(fw_global, tile_blk, *, expansion, packed):
 
     _, geo, use_kernel = expansion
     rows, cols, rtp, vtp, _ntp = geo
-    tiles, row_idx, col_id, sb_indptr, keys2d = tile_blk
-    if use_kernel:
-        cand = RM.expand_frontier_mxu(
-            fw_global, (tiles, row_idx, col_id, sb_indptr, keys2d),
-            rows=rows, cols=cols, rtp=rtp, vtp=vtp,
-        )
-    else:
-        cand = RM.expand_frontier_mxu_xla(
-            fw_global, (tiles, row_idx, col_id, sb_indptr, keys2d),
-            rows=rows, cols=cols, rtp=rtp, vtp=vtp,
-        )
+    expand = RM.expand_frontier_mxu if use_kernel else RM.expand_frontier_mxu_xla
+    cand = expand(fw_global, tuple(tile_blk), rows=rows, cols=cols, rtp=rtp,
+                  vtp=vtp)
     if packed:
         return cand
     return jnp.where(
@@ -1533,15 +1525,15 @@ def _sharded_adj_keys(srg) -> np.ndarray:
 
 
 def _sharded_tiles_dev(srg):
-    """Stacked per-shard MXU tile operands ``(tiles, row_idx, col_id,
-    sb_indptr, keys2d)`` (leading shard axis, per-shard tile counts
-    padded to the max with inert tiles) + the shared static geometry —
+    """Stacked per-shard MXU tile operands
+    (:func:`~bfs_tpu.ops.relay_mxu.mxu_operand_arrays`, leading shard
+    axis, per-shard tile counts padded to the max with inert tiles) + the shared static geometry —
     memoized on the layout object like the adjacency flavors."""
     cached = getattr(srg, "_mxu_tiles_dev", None)
     if cached is not None:
         return cached
     from ..graph.adj_tiles import TILE, TILE_WORDS, build_adj_tiles_sharded
-    from ..ops.relay_mxu import tiles_budget_bytes
+    from ..ops.relay_mxu import mxu_operand_arrays, tiles_budget_bytes
 
     per = build_adj_tiles_sharded(srg, budget_bytes=tiles_budget_bytes())
     ntp = max(at.ntp for at in per)
@@ -1562,14 +1554,12 @@ def _sharded_tiles_dev(srg):
             ),
         )
 
-    padded = [pad(at) for at in per]
-    ops = (
-        jnp.asarray(np.stack([p[0] for p in padded])),
-        jnp.asarray(np.stack([p[1] for p in padded])),
-        jnp.asarray(np.stack([p[2] for p in padded])),
-        jnp.asarray(np.stack([at.sb_indptr for at in per])),
-        jnp.asarray(np.stack([at.keys2d for at in per])),
-    )
+    shards = [
+        mxu_operand_arrays(*pad(at), at.sb_indptr, at.keys2d, rtp=at.rtp,
+                           vtp=at.vtp)
+        for at in per
+    ]
+    ops = tuple(jnp.asarray(np.stack(f)) for f in zip(*shards))
     geo = (per[0].rows, per[0].cols, per[0].rtp, per[0].vtp, ntp)
     cached = (ops, geo)
     object.__setattr__(srg, "_mxu_tiles_dev", cached)

@@ -25,6 +25,7 @@ import json
 import os
 import sys
 import time
+from collections import Counter
 
 #: Kernel arms forced for the whole run: a forced arm that fails raises.
 FORCED_ARMS = {
@@ -41,15 +42,37 @@ def _log(msg: str) -> None:
 
 class CompileMeter:
     """Backend compile seconds and persistent-cache hits, from JAX's own
-    monitoring events (a persistent-cache hit skips the backend compile)."""
+    monitoring events (a persistent-cache hit skips the backend compile),
+    and the names of the programs that missed the cache or were not
+    written to it, from JAX's compiler log."""
 
     def __init__(self):
+        import logging
+
         import jax
 
         self.seconds = 0.0
         self.compiles = 0
         self.cache_hits = 0
         self.cache_misses = 0
+        self.missed: list[str] = []
+        meter = self
+
+        class MissLog(logging.Handler):
+            def emit(self, record):
+                msg = record.getMessage()
+                if msg.startswith("PERSISTENT COMPILATION CACHE MISS"):
+                    meter.missed.append("miss " + msg.split("'")[1])
+                elif msg.startswith("Not writing persistent cache entry"):
+                    meter.missed.append("not written: " + msg[35:])
+                elif record.levelno >= logging.WARNING:
+                    print(self.format(record), file=sys.stderr)
+
+        # The compiler logs these at DEBUG: keep them here, off stderr.
+        log = logging.getLogger("jax._src.compiler")
+        log.addHandler(MissLog(logging.DEBUG))
+        log.setLevel(logging.DEBUG)
+        log.propagate = False
 
         def on_duration(event, duration, **_):
             if event.endswith("backend_compile_duration"):
@@ -67,7 +90,7 @@ class CompileMeter:
 
     def snapshot(self) -> tuple:
         return (self.seconds, self.compiles, self.cache_hits,
-                self.cache_misses)
+                self.cache_misses, len(self.missed))
 
 
 class Phase:
@@ -96,6 +119,7 @@ class Phase:
             "compiles": c1[1] - self.c0[1],
             "cache_hits": c1[2] - self.c0[2],
             "cache_misses": c1[3] - self.c0[3],
+            "missed": dict(Counter(self.meter.missed[self.c0[4]:])),
             "bytes_in_use": stats.get("bytes_in_use"),
             "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
             **self.facts,
@@ -110,6 +134,7 @@ class Oracle:
     def __init__(self, graph):
         self.graph = graph
         self._dist: dict[int, object] = {}
+        self._canon: dict[int, tuple] = {}
 
     def dist(self, root: int):
         from bfs_tpu.oracle.native import native_bfs
@@ -117,6 +142,27 @@ class Oracle:
         if root not in self._dist:
             self._dist[root] = native_bfs(self.graph, root)[0]
         return self._dist[root]
+
+    def canonical(self, root: int):
+        """The oracle's min-parent tree, the one every engine builds."""
+        from bfs_tpu.oracle.native import native_bfs
+
+        if root not in self._canon:
+            self._canon[root] = native_bfs(self.graph, root,
+                                           policy="canonical")[:2]
+        return self._canon[root]
+
+    def verify_exact(self, root: int, dist, parent, what: str) -> None:
+        """Distances and parents bit for bit against the canonical tree."""
+        import numpy as np
+
+        for name, got, want in zip(("distances", "parents"), (dist, parent),
+                                   self.canonical(root)):
+            got = np.asarray(got)
+            if got.shape != want.shape or not np.array_equal(got, want):
+                raise AssertionError(
+                    f"{what}: root {root}: {name} differ from the oracle"
+                )
 
     def verify(self, root: int, dist, parent, what: str) -> None:
         """Distances bit for bit, then the algs4 ``check()`` on the tree."""
@@ -231,8 +277,10 @@ def phase_multi(graph, rg, oracle, roots, args, ph: Phase) -> None:
 
 
 def phase_serve(graph, oracle, roots, args, ph: Phase) -> None:
-    """32 seeded dist/path point queries through ``BfsServer`` with the
-    label tier on; no breaker trip, host-oracle answer or quarantine."""
+    """32 seeded dist/path point queries through ``BfsServer`` (default
+    ``max_batch``) with the label tier on; the queries the labels leave
+    ride one element-major tick of 32 sources.  No breaker trip,
+    host-oracle answer or quarantine."""
     import numpy as np
 
     from bfs_tpu.cache.layout import LayoutCache
@@ -250,10 +298,7 @@ def phase_serve(graph, oracle, roots, args, ph: Phase) -> None:
         graph.src.astype(np.int64) * graph.num_vertices + graph.dst
     )
     registry = GraphRegistry(layout_cache=LayoutCache())
-    # max_batch 16 keeps ticks on the vmapped batch program: a 32-source
-    # tick runs element-major, whose Beneš kernel at s22 takes longer to
-    # compile than this whole run may (PERF.md, open questions).
-    with BfsServer(registry, engine="relay", max_batch=16) as srv:
+    with BfsServer(registry, engine="relay") as srv:
         srv.register("g", graph)
         srv.pause()  # admit every query, then serve them in one tick
         futures = [
@@ -263,6 +308,7 @@ def phase_serve(graph, oracle, roots, args, ph: Phase) -> None:
         srv.resume()
         replies = [f.result(timeout=1200) for f in futures]
         metrics = srv.metrics
+        batch = metrics.report()["batch_size_max"]
         counts = {
             k: metrics.count(k)
             for k in ("oracle_served", "breaker_opened", "device_errors",
@@ -289,13 +335,17 @@ def phase_serve(graph, oracle, roots, args, ph: Phase) -> None:
             raise AssertionError(f"serve counted {k}={counts[k]}: {counts}")
     if not counts["label_hits"]:
         raise AssertionError(f"label tier answered nothing: {counts}")
-    ph.facts.update(queries_verified=len(replies), methods=methods, **counts)
+    if batch != 32:
+        # The server's default max_batch is 32: the queries the labels do
+        # not answer ride one tick padded to 32 sources, which runs the
+        # element-major program (serve/executor.py).
+        raise AssertionError(f"device tick of {batch} sources, not 32")
+    ph.facts.update(queries_verified=len(replies), device_batch=batch,
+                    methods=methods, **counts)
 
 
 def phase_mesh(graph, oracle, roots, args, ph: Phase) -> None:
     """The 1D sharded relay on x4 and the 2x2 grid, against the oracle."""
-    import jax
-
     from bfs_tpu.graph.relay import build_sharded_relay_graph
     from bfs_tpu.parallel.grid import bfs_grid, make_grid_mesh
     from bfs_tpu.parallel.sharded import bfs_sharded, make_mesh
@@ -309,13 +359,15 @@ def phase_mesh(graph, oracle, roots, args, ph: Phase) -> None:
         ids = {d.id for d in mesh.devices.flat}
         if len(ids) != 4:
             raise AssertionError(f"mesh spans {len(ids)} devices, not 4")
-    devs = {d.id for d in jax.devices()}
+    devs = {d.id for m in (mesh1d, grid) for d in m.devices.flat}
+    # Both meshes build the canonical min-parent tree: comparing it bit
+    # for bit is stronger than check() and a fraction of its host time.
     for root in roots:
         res = bfs_sharded(srg, root, mesh=mesh1d, engine="relay")
-        oracle.verify(root, res.dist, res.parent, "sharded x4")
+        oracle.verify_exact(root, res.dist, res.parent, "sharded x4")
         res = bfs_grid(srg, root, mesh=grid, direction="auto",
                        exchange="auto")
-        oracle.verify(root, res.dist, res.parent, "grid 2x2")
+        oracle.verify_exact(root, res.dist, res.parent, "grid 2x2")
     ph.facts.update(roots_verified=len(roots), devices=sorted(devs))
 
 

@@ -105,7 +105,16 @@ def test_apply_benes_fused_end_to_end():
     np.testing.assert_array_equal(np.asarray(unpack_std(out, n)), bits[perm])
 
 
-def test_elem_fused_passes_match_reference():
+@pytest.mark.parametrize(
+    "outer_bits, modes",
+    [
+        (8, ["outer", "local", "outer"]),
+        # one block bit per outer pass: every outer pass is strided over
+        # the block axis (hi > 1 or tr > tile rows)
+        (1, ["outer", "outer", "local", "outer", "outer"]),
+    ],
+)
+def test_elem_fused_passes_match_reference(outer_bits, modes):
     """Element-major fused passes (uint32 per element, vertically-packed
     masks) route exactly perm on whole uint32 payloads, both groups."""
     import jax.numpy as jnp
@@ -121,19 +130,20 @@ def test_elem_fused_passes_match_reference():
     n = 1 << 16
     perm = rng.permutation(n).astype(np.int64)
     masks, table = _compact_and_table(benes.route_std(perm), n)
-    ps = elem_pass_static(table, n, tile_rows=128, outer_tt=32)
+    ps = elem_pass_static(table, n, tile_rows=128, outer_tt=32,
+                          outer_bits=outer_bits)
     arrays = [
         jnp.asarray(a)
         for a in prepare_elem_pass_masks(masks, table, n, tile_rows=128,
-                                         outer_tt=32)
+                                         outer_tt=32, outer_bits=outer_bits)
     ]
-    assert [m[0] for m in ps] == ["outer", "local", "outer"]
+    assert [p[0] for p in ps] == modes
     x = rng.integers(0, 2**32, (2, n), dtype=np.uint32)
     want = np.asarray(
         apply_benes_elem(jnp.asarray(x), jnp.asarray(masks), table, n)
     )
     np.testing.assert_array_equal(want, x[:, perm])
     got = jnp.asarray(x)
-    for (mode, tr, tt, specs), arr in zip(ps, arrays):
-        got = _run_elem_pass(got, arr, mode, tr, tt, specs, n, True)
+    for (mode, tr, tt, specs, m), arr in zip(ps, arrays):
+        got = _run_elem_pass(got, arr, mode, tr, tt, specs, m, n, True)
     np.testing.assert_array_equal(np.asarray(got), want)

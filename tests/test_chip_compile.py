@@ -5,8 +5,7 @@ No chip is needed: the TPU compiler in the installed libtpu compiles for a
 Mosaic would refuse on the chip (unaligned slices, VMEM or SMEM over the
 limit, unsupported casts and reductions) — none of which interpret-mode
 tests can see.  Shapes are the s22 ef16 Graph500 R-MAT layout's (net
-2^28, vperm 2^23, vr 4194528, the real in-degree classes), except the
-element-major pass, whose s22 tile takes minutes to compile (PERF.md).
+2^28, vperm 2^23, vr 4194528, the real in-degree classes).
 """
 
 from __future__ import annotations
@@ -152,12 +151,25 @@ def test_benes_row_major_local_pass_compiles(one_chip, layout_knobs):
     )
 
 
-def test_benes_elem_pass_compiles(one_chip):
-    """The element-major pass (32 trees per element), at a net whose
-    local tile is 256 element rows."""
-    n = 1 << 15
+def _elem_passes(n: int):
+    """The element-major passes of an n-element net, one per distinct
+    block shape (mode, m)."""
+    seen, out = set(), []
     for p in RP.elem_pass_static(_table(n), n):
-        rows = sum(st.nwords for st in p[3]) // RP.LANES
+        if (p[0], p[4]) not in seen:
+            seen.add((p[0], p[4]))
+            out.append((p, sum(st.nwords for st in p[3]) // RP.LANES))
+    return out
+
+
+@pytest.mark.parametrize("mode", ["local", "outer"])
+def test_benes_elem_pass_compiles(one_chip, mode):
+    """The element-major passes (32 trees per element) of the s22 net:
+    the local pass and each outer window shape."""
+    n = S22_NET
+    passes = [x for x in _elem_passes(n) if x[0][0] == mode]
+    assert passes
+    for p, rows in passes:
         _compile(
             lambda x, a, p=p: RP.apply_benes_elem_fused(x, (a,), (p,), n),
             _shape(one_chip, (1, n)), _shape(one_chip, (rows, RP.LANES)),
@@ -203,11 +215,13 @@ def test_mxu_expansion_compiles(one_chip):
     vtp = -(-cols // MX.SB_VERTS) * MX.SB_VERTS
     ntp = 1 << 20
     ops = (
-        _shape(one_chip, (ntp, MX.TILE, MX.TILE_WORDS)),
+        _shape(one_chip, (ntp // 2, 2 * MX.TILE_WORDS, MX.TILE)),
         _shape(one_chip, (ntp,), jnp.int32),
         _shape(one_chip, (ntp,), jnp.int32),
         _shape(one_chip, (vtp // MX.SB_VERTS + 1,), jnp.int32),
-        _shape(one_chip, (rtp // MX.TILE + 1, MX.TILE)),
+        _shape(one_chip, (-(-(rtp // MX.TILE + 1) // 8), 8, MX.TILE)),
+        _shape(one_chip, (ntp // MX._IDX_CHUNK, 2, MX._IDX_CHUNK),
+               jnp.int32),
     )
     _compile(
         lambda fw, o: MX.expand_frontier_mxu(
