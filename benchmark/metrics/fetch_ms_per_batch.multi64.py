@@ -1,0 +1,12 @@
+"""Host milliseconds per batch of sources in the copy of the finished
+program's output to the host (program span ``relay.fetch``)."""
+
+from benchmark.spans import span_seconds
+
+
+def read(run):
+    if run.trace is None or run.kind != "closed_search" \
+            or run.facts.get("batch", 1) == 1 or not run.requests:
+        return None
+    seconds = span_seconds(run.trace, "relay.fetch")
+    return None if seconds is None else seconds * 1e3 / run.requests

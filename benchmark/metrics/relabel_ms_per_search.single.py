@@ -1,0 +1,12 @@
+"""Host milliseconds per single-source search in the engine's relabel of
+the fetched result to the original ids (program span ``relay.relabel``)."""
+
+from benchmark.spans import span_seconds
+
+
+def read(run):
+    if run.trace is None or run.kind != "closed_search" \
+            or run.facts.get("batch") != 1 or not run.searches:
+        return None
+    seconds = span_seconds(run.trace, "relay.relabel")
+    return None if seconds is None else seconds * 1e3 / run.searches

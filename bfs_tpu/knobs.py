@@ -464,7 +464,8 @@ KNOBS: dict[str, Knob] = {k.name: k for k in (
        "stdlib logging level for the project loggers",
        canary="CHATTY"),
     _k("BFS_TPU_SPANS", "flag", "1", _flag(),
-       "phase-span telemetry ledger; 0 disables",
+       "phase spans: Chrome-trace buffer and jax.profiler annotations; "
+       "0 disables both",
        canary="yes"),
     _k("BFS_TPU_BUILD_LOG", "flag", "0", _flag(),
        "per-build layout/relay build-step logging (bench turns it on)",

@@ -1,20 +1,32 @@
-"""Nestable wall-clock spans exported as Chrome trace events.
+"""Nestable wall-clock spans, exported as Chrome trace events and
+written into the profiler's trace.
 
-The span buffer is process-global and bounded; each closed span becomes
-one Chrome ``"ph": "X"`` complete event (name, ts/dur in µs, pid/tid),
-which Perfetto and ``chrome://tracing`` load directly — nesting is
-inferred from containment on the same tid, so the API never needs an
-explicit parent handle.  ``ts`` is wall-clock epoch µs (not a process
-monotonic zero): a bench killed and resumed journals each segment's
-events as they happened, and the stitched trace shows the gap between
-process generations instead of overlapping them.
+One span has two sinks:
 
-Spans are ON by default (``BFS_TPU_SPANS=0`` disables): one
-``perf_counter_ns`` pair plus a dict append per span, host-side only —
-nothing here ever touches a device value, which is what keeps the API
-legal anywhere EXCEPT inside a declared hot region (the analysis pass's
-OBS001 polices reads; span *writes* around a hot region are the intended
-use: ``with span("repeat"): run()``).
+- The bounded process-global buffer: each closed span becomes one Chrome
+  ``"ph": "X"`` complete event (name, ts/dur in µs, pid/tid, args),
+  which Perfetto and ``chrome://tracing`` load directly.  Each event also
+  records its own ``id`` and its ``parent``: the id of the innermost span
+  open on the same thread when it began (None at the top), so a reader
+  gets the causal tree without inferring it from containment.  ``ts`` is
+  wall-clock epoch µs (not a process monotonic zero): a bench killed and
+  resumed journals each segment's events as they happened, and the
+  stitched trace shows the gap between process generations instead of
+  overlapping them.
+- The profiler: when ``jax`` is already imported, the span also enters a
+  ``jax.profiler.TraceAnnotation`` under its bare name (the attributes
+  stay in the Chrome export).  With a ``jax.profiler`` trace running, the
+  span lands on the thread's line of the trace, on the same clock as the
+  device's operations, so a device gap can be laid against the program's
+  own phases (``relay.fetch``, ``relay.relabel``, ...).  With no trace
+  running the annotation costs about half a microsecond.
+
+Spans are ON by default (``BFS_TPU_SPANS=0`` disables both sinks): one
+``perf_counter_ns`` pair, a dict append and the annotation per span,
+host-side only — nothing here ever touches a device value, which is what
+keeps the API legal anywhere EXCEPT inside a declared hot region (the
+analysis pass's OBS001 polices reads; span *writes* around a hot region
+are the intended use: ``with span("repeat"): run()``).
 
 Crash-durable traces: :func:`journal_spans` drains the buffer into a
 ``RunJournal`` record (``spans:<k>``, one per process generation) and
@@ -24,7 +36,8 @@ first (:func:`flush_open_spans`) so an interrupted run leaves a usable
 trace instead of a truncated one.
 
 Everything in this module is stdlib-only (no jax, no numpy): the lint
-stub path (tools/lint.py, tools/chaos_run.py) imports it for free.
+stub path (tools/lint.py, tools/chaos_run.py) imports it for free, and
+the profiler sink is used only once something else has imported jax.
 """
 
 from __future__ import annotations
@@ -32,6 +45,7 @@ from __future__ import annotations
 import functools
 import json
 import os
+import sys
 import threading
 import time
 
@@ -47,6 +61,9 @@ _events: list[dict] = []  # guarded-by: _lock
 _dropped = 0  # guarded-by: _lock
 _open: dict[int, dict] = {}  # guarded-by: _lock — span id -> start info
 _next_id = [0]  # guarded-by: _lock
+#: Per-thread stack of open span ids: the innermost is the parent of the
+#: next span or marker the thread records.
+_stack = threading.local()
 
 
 def spans_enabled() -> bool:
@@ -66,20 +83,41 @@ def _emit(event: dict) -> None:
         _events.append(event)
 
 
+def _thread_stack() -> list:
+    ids = getattr(_stack, "ids", None)
+    if ids is None:
+        ids = _stack.ids = []
+    return ids
+
+
+def _annotation(name: str):
+    """An entered ``jax.profiler.TraceAnnotation`` named ``name``, or None
+    while jax is not imported (this module never imports it first)."""
+    if "jax" not in sys.modules:
+        return None
+    from jax.profiler import TraceAnnotation
+
+    ann = TraceAnnotation(name)
+    ann.__enter__()
+    return ann
+
+
 class _Span:
     """One span: context manager AND decorator (``@span("name")``)."""
 
-    __slots__ = ("name", "attrs", "_id", "_t0")
+    __slots__ = ("name", "attrs", "_id", "_t0", "_ann")
 
     def __init__(self, name: str, attrs: dict):
         self.name = name
         self.attrs = attrs
         self._id = None
         self._t0 = 0
+        self._ann = None
 
     def __enter__(self):
         if not spans_enabled():
             return self
+        ids = _thread_stack()
         self._t0 = time.perf_counter_ns()
         with _lock:
             _next_id[0] += 1
@@ -90,13 +128,22 @@ class _Span:
                 "t0": self._t0,
                 "tid": threading.get_ident(),
                 "args": dict(self.attrs),
+                "parent": ids[-1] if ids else None,
             }
+        ids.append(self._id)
+        self._ann = _annotation(self.name)
         return self
 
     def __exit__(self, exc_type, exc, tb):
         if self._id is None:
             return False
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
         dur_us = (time.perf_counter_ns() - self._t0) // 1_000
+        ids = _thread_stack()
+        if self._id in ids:
+            ids.remove(self._id)
         with _lock:
             info = _open.pop(self._id, None)
         if info is not None:
@@ -107,6 +154,7 @@ class _Span:
                 "name": self.name, "ph": "X", "ts": info["ts"],
                 "dur": max(int(dur_us), 1), "pid": os.getpid(),
                 "tid": info["tid"], "cat": "bfs_tpu", "args": args,
+                "id": self._id, "parent": info["parent"],
             })
         return False
 
@@ -129,10 +177,15 @@ def instant(name: str, **attrs) -> None:
     cache invalidation, fault injection: things that happen, not last."""
     if not spans_enabled():
         return
+    ids = _thread_stack()
+    with _lock:
+        _next_id[0] += 1
+        own = _next_id[0]
     _emit({
         "name": name, "ph": "i", "ts": _wall_us(), "s": "p",
         "pid": os.getpid(), "tid": threading.get_ident(),
         "cat": "bfs_tpu", "args": dict(attrs),
+        "id": own, "parent": ids[-1] if ids else None,
     })
 
 
@@ -144,14 +197,15 @@ def flush_open_spans(note: str = "flushed") -> int:
     is about to exit."""
     now_ns = time.perf_counter_ns()
     with _lock:
-        open_now = list(_open.values())
+        open_now = list(_open.items())
         _open.clear()
-    for info in open_now:
+    for sid, info in open_now:
         _emit({
             "name": info["name"], "ph": "X", "ts": info["ts"],
             "dur": max((now_ns - info["t0"]) // 1_000, 1),
             "pid": os.getpid(), "tid": info["tid"], "cat": "bfs_tpu",
             "args": {**info["args"], "flushed": note},
+            "id": sid, "parent": info["parent"],
         })
     return len(open_now)
 

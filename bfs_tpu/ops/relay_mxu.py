@@ -471,6 +471,7 @@ def expand_frontier_mxu(
             pltpu.SemaphoreType.DMA((4,)),
         ],
         interpret=interpret,
+        name="mxu_expand",
     )(sb_indptr, idx, tiles_wm, fblk, keys_b)
     out = out.reshape(-1)[:cols]
     return jnp.where(

@@ -36,7 +36,7 @@ LANES = 128
 #: pass-B tile rows: 256 rows * 128 lanes * 4 B = 128 KB of VMEM for x.
 #: Mosaic unrolls each stage over the tile's vregs, so cold compile time
 #: grows steeply with the tile (PERF.md, PR 21).  Env-tunable for on-chip
-#: sweeps (tools/profile_net_kernel.py).
+#: sweeps.
 TILE_ROWS = knobs.get("BFS_TPU_TILE_ROWS")
 #: outer-pass inner-chunk rows; the x block is (B, OUTER_TT, 128).
 OUTER_TT = knobs.get("BFS_TPU_OUTER_TT")
@@ -442,6 +442,7 @@ def _run_local_tile_major(x, arr2d, tr, specs, n, interpret, vma=None):
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=interpret,
+        name="relay_net_local",
     )(x_view, arr2d)
     return out.reshape(-1)
 
@@ -602,6 +603,7 @@ def _run_pass(x, arr2d, mode, tr, tt, specs, n, interpret, vma=None,
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=interpret,
+        name=f"relay_net_{mode}",
     )(*operands)
     return out.reshape(-1)
 
@@ -885,6 +887,7 @@ def _run_elem_pass(x, arr2d, mode, tr, tt, specs, m, n, interpret):
             pltpu.SemaphoreType.DMA((depth,)),
         ],
         interpret=interpret,
+        name="relay_net_elem",
     )(x_view, arr2d)
     return out.reshape(g, n)
 
@@ -1077,6 +1080,7 @@ def _class_tournament_call(x2d, v2d, width: int, cw: int, interpret: bool):
         out_specs=pl.BlockSpec((nb + 1, chunk), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((nb + 1, cw), jnp.uint32),
         interpret=interpret,
+        name="relay_rowmin",
     )(x2d, v2d)
 
 
@@ -1217,6 +1221,7 @@ def apply_relay_candidates_packed_pallas(
             jax.ShapeDtypeStruct((rows, LANES), jnp.uint32),
         ),
         interpret=interpret,
+        name="relay_apply_packed",
     )(pk.reshape(rows, LANES), cand.reshape(rows, LANES))
     packed2 = pk2.reshape(-1)[:vr]
     fwords = fw[:, ::32].reshape(-1)[: vr // 32]

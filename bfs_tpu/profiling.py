@@ -23,8 +23,8 @@ timing difference cancels dispatch + sync overhead — the same
 methodology as the applier probe (models/bfs.py).
 
 The ledger is CPU-runnable (tests and ``python -m bfs_tpu.profiling``
-run it on a small R-MAT without any TPU), ships in the bench headline as
-``details.superstep_phases``, and backs tools/profile_superstep.py.
+run it on a small R-MAT without any TPU) and ships in the bench headline
+as ``details.superstep_phases``.
 
 Analytic bytes are the MINIMUM HBM traffic of each phase (operands read
 once + outputs written once); a measured phase time far above
@@ -704,8 +704,7 @@ def probe_phase_kernels(eng, *, loops: int = 4, repeats: int = 2) -> dict:
 
 def main() -> None:
     """CPU-runnable microbench: build a small R-MAT, run the ledger, print
-    JSON (the standalone evidence path; tools/profile_superstep.py is the
-    TPU-scale twin)."""
+    JSON (the standalone evidence path)."""
     import argparse
     import json
 

@@ -10,7 +10,9 @@ tests can see.  Shapes are the s22 ef16 Graph500 R-MAT layout's (net
 
 from __future__ import annotations
 
+import ast
 import os
+import re
 
 import pytest
 
@@ -85,9 +87,14 @@ def _shape(sharding, shape, dtype=jnp.uint32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _compile(fn, *args):
-    """Compile for the described chip; raises what Mosaic/XLA refuse."""
-    return jax.jit(fn).lower(*args).compile(compiler_options=OPTIONS)
+def _compile(fn, *args, kernel: str):
+    """Compile for the described chip; raises what Mosaic/XLA refuse.  The
+    compiled module must hold the kernel as a custom call under its
+    ``name=``, the name the device trace shows."""
+    compiled = jax.jit(fn).lower(*args).compile(compiler_options=OPTIONS)
+    assert re.search(rf"%{kernel}(\.\d+)? = .*custom-call\(",
+                     compiled.as_text()), kernel
+    return compiled
 
 
 def _table(n: int) -> tuple[StageSpec, ...]:
@@ -137,6 +144,7 @@ def test_benes_pass_compiles(one_chip, layout_knobs, mode):
     _compile(
         lambda w, a: RP.apply_benes_fused(w, (a,), (p,), n),
         _shape(one_chip, (n // 32,)), _shape(one_chip, (rows, RP.LANES)),
+        kernel={"outer": "relay_net_outer", "local_tm": "relay_net_local"}[mode],
     )
 
 
@@ -148,6 +156,7 @@ def test_benes_row_major_local_pass_compiles(one_chip, layout_knobs):
     _compile(
         lambda w, a: RP.apply_benes_fused(w, (a,), (p,), n),
         _shape(one_chip, (n // 32,)), _shape(one_chip, (rows, RP.LANES)),
+        kernel="relay_net_local",
     )
 
 
@@ -173,6 +182,7 @@ def test_benes_elem_pass_compiles(one_chip, mode):
         _compile(
             lambda x, a, p=p: RP.apply_benes_elem_fused(x, (a,), (p,), n),
             _shape(one_chip, (1, n)), _shape(one_chip, (rows, RP.LANES)),
+            kernel="relay_net_elem",
         )
 
 
@@ -185,7 +195,7 @@ def test_rowmin_compiles(one_chip):
         lambda l1, v: RP.rowmin_ranks_pallas(
             l1, v, classes, S22_VR, interpret=False
         ),
-        words, words,
+        words, words, kernel="relay_rowmin",
     )
 
 
@@ -201,7 +211,7 @@ def test_state_update_compiles(one_chip):
         lambda s, c: RP.apply_relay_candidates_packed_pallas(
             s, c, interpret=False
         ),
-        st, _shape(one_chip, (S22_VR,)),
+        st, _shape(one_chip, (S22_VR,)), kernel="relay_apply_packed",
     )
 
 
@@ -227,5 +237,38 @@ def test_mxu_expansion_compiles(one_chip):
         lambda fw, o: MX.expand_frontier_mxu(
             fw, o, rows=rows, cols=cols, rtp=rtp, vtp=vtp, interpret=False
         ),
-        _shape(one_chip, (S22_VR // 32,)), ops,
+        _shape(one_chip, (S22_VR // 32,)), ops, kernel="mxu_expand",
     )
+
+
+#: The name each Pallas call site gives its kernel: the name under which
+#: the device trace shows it, and which the benchmark's readers select.
+KERNEL_NAMES = {
+    "bfs_tpu/ops/relay_pallas.py::_run_local_tile_major": "'relay_net_local'",
+    "bfs_tpu/ops/relay_pallas.py::_run_pass": "f'relay_net_{mode}'",
+    "bfs_tpu/ops/relay_pallas.py::_run_elem_pass": "'relay_net_elem'",
+    "bfs_tpu/ops/relay_pallas.py::_class_tournament_call": "'relay_rowmin'",
+    "bfs_tpu/ops/relay_pallas.py::apply_relay_candidates_packed_pallas":
+        "'relay_apply_packed'",
+    "bfs_tpu/ops/relay_mxu.py::expand_frontier_mxu": "'mxu_expand'",
+}
+
+
+def test_every_pallas_call_names_its_kernel():
+    """Every ``pallas_call`` in ``bfs_tpu/`` passes ``name=``; the names
+    are the table's, so a new call site has to be named here too."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    found = {}
+    for rel in sorted({site.split("::")[0] for site in KERNEL_NAMES}):
+        tree = ast.parse(open(os.path.join(repo, rel), encoding="utf-8").read())
+        for fn in tree.body:
+            for node in ast.walk(fn):
+                if isinstance(node, ast.Call) and getattr(
+                        node.func, "attr", None) == "pallas_call":
+                    name = [k.value for k in node.keywords if k.arg == "name"]
+                    found[f"{rel}::{fn.name}"] = (
+                        ast.unparse(name[0]) if name else None)
+    assert found == KERNEL_NAMES
+    from bfs_tpu.analysis.pallas import discover_pallas_sites
+
+    assert discover_pallas_sites(repo) == set(KERNEL_NAMES)

@@ -163,6 +163,195 @@ def test_journal_roundtrip_through_resumed_run(tmp_path):
     jr3.close()
 
 
+def _profiled(fn, trace_dir) -> list:
+    """``(name, start_ns, end_ns)`` of the profiler's host events on the
+    thread that ran ``fn`` under a CPU ``jax.profiler`` trace."""
+    import glob
+    import os
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(trace_dir))
+    try:
+        with jax.profiler.TraceAnnotation("test.window"):
+            fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                        recursive=True)
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            events = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+                      for ev in line.events]
+            if any(name == "test.window" for name, _, _ in events):
+                return events
+    raise AssertionError("no host line of the trace holds the window")
+
+
+def _nesting(events, prefix: str) -> list:
+    """``[(name, depth), ...]`` of the events named ``prefix*`` in start
+    order, depth counted by containment among them."""
+    picked = sorted((e for e in events if e[0].startswith(prefix)),
+                    key=lambda e: (e[1], -e[2]))
+    out, stack = [], []
+    for name, s, e in picked:
+        while stack and stack[-1] <= s:
+            stack.pop()
+        out.append((name, len(stack)))
+        stack.append(e)
+    return out
+
+
+def test_span_opens_a_profiler_annotation(tmp_path):
+    """A span is also a profiler annotation under its bare name, nested
+    on the thread's line like the span itself; attributes stay in the
+    Chrome export."""
+    def work():
+        with span("unit.outer", rows=3):
+            with span("unit.inner"):
+                pass
+
+    events = _profiled(work, tmp_path / "trace")
+    assert _nesting(events, "unit.") == [("unit.outer", 0), ("unit.inner", 1)]
+    assert [e["name"] for e in snapshot_events()] == ["unit.inner", "unit.outer"]
+
+
+def test_exported_events_carry_id_and_parent():
+    with span("outer"):
+        with span("inner"):
+            instant("mark")
+        with span("sibling"):
+            pass
+    with span("top"):
+        pass
+    ev = {e["name"]: e for e in snapshot_events()}
+    ids = [e["id"] for e in ev.values()]
+    assert len(set(ids)) == len(ids)
+    assert ev["outer"]["parent"] is None and ev["top"]["parent"] is None
+    assert ev["inner"]["parent"] == ev["outer"]["id"]
+    assert ev["sibling"]["parent"] == ev["outer"]["id"]
+    assert ev["mark"]["parent"] == ev["inner"]["id"]
+    # Round-trips through the Chrome export.
+    doc = json.loads(json.dumps(chrome_trace()))
+    assert {e["name"]: e["parent"] for e in doc["traceEvents"]}["inner"] == \
+        ev["outer"]["id"]
+
+
+def test_flushed_span_keeps_its_parent():
+    outer = span("outer")
+    outer.__enter__()
+    with span("inner"):
+        pass
+    flush_open_spans("signal:SIGTERM")
+    outer.__exit__(None, None, None)
+    ev = {e["name"]: e for e in snapshot_events()}
+    assert ev["outer"]["args"]["flushed"] == "signal:SIGTERM"
+    assert ev["outer"]["parent"] is None
+    assert ev["inner"]["parent"] == ev["outer"]["id"]
+
+
+def test_spans_off_emit_into_neither_sink(monkeypatch, tmp_path):
+    monkeypatch.setenv("BFS_TPU_SPANS", "0")
+
+    def work():
+        with span("unit.invisible"):
+            instant("unit.also_invisible")
+
+    events = _profiled(work, tmp_path / "trace")
+    assert not [e for e in events if e[0].startswith("unit.")]
+    assert snapshot_events() == []
+
+
+#: The engine's spans of one search that re-runs unpacked, in start order
+#: with their depth below the search's own span.
+SEARCH_SPANS = [
+    ("relay.launch", 1), ("relay.device_wait", 1), ("relay.fetch", 1),
+    ("relay.rerun_unpacked", 1),
+    ("relay.launch", 2), ("relay.device_wait", 2), ("relay.fetch", 2),
+    ("relay.relabel", 1),
+]
+
+
+def test_engine_spans_and_counters(tmp_path):
+    """``RelayEngine.run`` and ``run_multi`` on a path deeper than the
+    packed level field: every boundary appears nested in order on the
+    profiler's line, and the counters move by one search (a batch by its
+    trees), one re-run each, and the bytes of every fetch."""
+    from bfs_tpu.models.bfs import RelayEngine
+
+    g = path_graph(70)
+    eng = RelayEngine(g)
+    assert eng.packed
+    sources = np.array([0, 5, 9], np.int32)
+    eng.run(0)  # compile outside the trace
+    eng.run_multi(sources)
+    obs_spans.drain_events()
+    reg = obs_registry.get_registry()
+    names = ("relay_searches", "relay_unpacked_reruns", "relay_fetch_bytes")
+    before = {k: reg.count(k) for k in names}
+    out = {}
+
+    def work():
+        out["single"] = eng.run(0)
+        out["multi"] = eng.run_multi(sources)
+
+    events = _profiled(work, tmp_path / "trace")
+    assert out["single"].dist.tolist() == list(range(70))
+    assert out["multi"].dist[1].tolist() == [abs(v - 5) for v in range(70)]
+    assert _nesting(events, "relay.") == (
+        [("relay.search", 0)] + SEARCH_SPANS
+        + [("relay.batch", 0)] + SEARCH_SPANS)
+    moved = {k: reg.count(k) - before[k] for k in names}
+    fetched = [e for e in snapshot_events() if e["name"] == "relay.fetch"]
+    assert len(fetched) == 4
+    # Each fetch carries at least the dist and parent words of its trees.
+    for e, trees in zip(fetched, (1, 1, 3, 3)):
+        assert e["args"]["bytes"] >= trees * 2 * 4 * 70
+    assert moved == {"relay_searches": 1 + 3, "relay_unpacked_reruns": 2,
+                     "relay_fetch_bytes": sum(e["args"]["bytes"] for e in fetched)}
+    ev = {(e["name"], e["id"]): e for e in snapshot_events()}
+    by_id = {e["id"]: e["name"] for e in ev.values()}
+    for (name, _), e in ev.items():
+        if name in ("relay.search", "relay.batch"):
+            assert e["parent"] is None
+        elif name in ("relay.rerun_unpacked", "relay.relabel"):
+            assert by_id[e["parent"]] in ("relay.search", "relay.batch")
+    assert [e["args"] for e in snapshot_events()
+            if e["name"] == "relay.batch"] == [{"batch": 3}]
+
+
+def test_engine_queues_the_host_copy_before_the_wait(monkeypatch):
+    """The copy to the host is queued at dispatch, as ``jax.device_get``
+    queues it, so it runs as the program ends: waiting first and copying
+    after left the chip idle for the host's wake-up on every search."""
+    import jax
+    from jax._src.array import ArrayImpl
+
+    from bfs_tpu.models.bfs import RelayEngine
+
+    eng = RelayEngine(rmat_graph(7, 8, seed=3))
+    eng.run(0)
+    order = []
+    real_copy, real_wait = ArrayImpl.copy_to_host_async, jax.block_until_ready
+
+    def copy(self):
+        order.append("copy")
+        return real_copy(self)
+
+    def wait(x):
+        order.append("wait")
+        return real_wait(x)
+
+    monkeypatch.setattr(ArrayImpl, "copy_to_host_async", copy)
+    monkeypatch.setattr(jax, "block_until_ready", wait)
+    eng.run(1)
+    first_wait = order.index("wait")
+    assert first_wait >= 5 and set(order[:first_wait]) == {"copy"}
+
+
 def test_event_buffer_bounded(monkeypatch):
     monkeypatch.setattr(obs_spans, "MAX_EVENTS", 3)
     for i in range(5):
