@@ -19,7 +19,10 @@ from __future__ import annotations
 
 import functools
 import logging
+import os
 import sys
+import threading
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 
 import jax
@@ -264,6 +267,109 @@ def slots_to_parent(parent_slots: np.ndarray, src_l1: np.ndarray) -> np.ndarray:
     on device (ops/relay.rowmin_candidates)."""
     slots = np.clip(parent_slots, 0, src_l1.shape[-1] - 1)
     return np.where(parent_slots >= 0, src_l1[slots], parent_slots).astype(np.int32)
+
+
+#: Outputs under this many values relabel inline on the calling thread
+#: (tests, serving on small graphs); larger ones split into parts of at
+#: least this size over the shared pool.
+RELABEL_INLINE_MAX = 1 << 16
+#: Most threads one relabel runs on: the gathers are memory-bound, so
+#: more threads than this buy nothing on a host.
+RELABEL_MAX_WORKERS = 16
+
+_relabel_pool_lock = threading.Lock()
+_relabel_pool: list = [None, None]  # guarded-by: _relabel_pool_lock — [pid, executor]
+
+
+@functools.cache
+def _host_cores() -> int:
+    affinity = getattr(os, "sched_getaffinity", None)
+    return len(affinity(0)) if affinity else (os.cpu_count() or 1)
+
+
+def _relabel_executor() -> ThreadPoolExecutor:
+    """The process's relabel pool, created on first use (threads start
+    lazily, as parts arrive) and again in a forked child, whose copy
+    holds no live threads."""
+    with _relabel_pool_lock:
+        pid, pool = _relabel_pool
+        if pool is None or pid != os.getpid():
+            pool = ThreadPoolExecutor(RELABEL_MAX_WORKERS,
+                                      thread_name_prefix="bfs-relabel")
+            _relabel_pool[:] = [os.getpid(), pool]
+        return pool
+
+
+def relabel_workers(rows: int, num_vertices: int,
+                    workers: int | None = None) -> int:
+    """Threads :func:`relabel_to_original` runs ``rows`` trees of
+    ``num_vertices`` values on: ``workers`` if given, else one per part
+    of :data:`RELABEL_INLINE_MAX` values up to the host's cores; at most
+    :data:`RELABEL_MAX_WORKERS` and one per output value."""
+    n_out = rows * num_vertices
+    if workers is None:
+        workers = min(_host_cores(), -(-n_out // RELABEL_INLINE_MAX))
+    return max(1, min(int(workers), RELABEL_MAX_WORKERS, n_out))
+
+
+def relabel_to_original(dist_new, parent_new, old2new, src_l1, *,
+                        slots: bool, workers: int | None = None):
+    """Relabeled-space ``(dist, parent)`` of one search (1-D) or a batch
+    (2-D, a tree per row) -> ORIGINAL id space: the values of
+    ``dist_new[..., old2new]`` and ``slots_to_parent(parent_new,
+    src_l1)[..., old2new]`` (``slots``; else ``parent_new[...,
+    old2new]``: the mxu arm's parent values are original ids already),
+    made in one gather pass per part.
+
+    Mapping slots to ids is elementwise, so it commutes with the
+    permutation: each part gathers through ``old2new`` straight into the
+    int32 outputs, then maps only those parent values through ``src_l1``;
+    negative parents pass through unchanged.  A part is a row of a batch
+    or a contiguous range of original ids of one search; the parts run on
+    :func:`relabel_workers` threads of the process's pool (``np.take``
+    releases the GIL), inline when that is one.  Scratch is per part.
+    ``old2new`` should be intp, or numpy copies the indices per call.
+    Callers fix up each source's self-parent."""
+    dist_new, parent_new = np.asarray(dist_new), np.asarray(parent_new)
+    o2n = np.asarray(old2new, dtype=np.intp)
+    table = np.asarray(src_l1) if slots else None
+    lead, v = dist_new.shape[:-1], o2n.shape[0]
+    rows = int(np.prod(lead, dtype=np.int64))
+    dist = np.empty(lead + (v,), dist_new.dtype)
+    parent = np.empty(lead + (v,), np.int32)
+    d_in, p_in = dist_new.reshape(rows, -1), parent_new.reshape(rows, -1)
+    d_out, p_out = dist.reshape(rows, v), parent.reshape(rows, v)
+    n = relabel_workers(rows, v, workers)
+    chunks = 1 if rows >= n else -(-n // rows)
+    bounds = [v * k // chunks for k in range(chunks + 1)]
+    parts = [(r, lo, hi) for r in range(rows)
+             for lo, hi in zip(bounds, bounds[1:]) if hi > lo]
+
+    def one(r, lo, hi):
+        # mode="clip": "raise" would buffer each output; old2new and the
+        # gathered slots are in range or clip as slots_to_parent does.
+        idx = o2n[lo:hi]
+        np.take(d_in[r], idx, out=d_out[r, lo:hi], mode="clip")
+        if table is None:
+            np.take(p_in[r], idx, out=p_out[r, lo:hi], mode="clip")
+            return
+        got = np.take(p_in[r], idx, mode="clip")
+        out = p_out[r, lo:hi]
+        np.take(table, got, out=out, mode="clip")
+        np.copyto(out, got, where=got < 0)
+
+    def run(share):
+        for part in share:
+            one(*part)
+
+    if n == 1:
+        run(parts)
+    else:
+        pool = _relabel_executor()
+        for f in [pool.submit(run, parts[k::n]) for k in range(n)]:
+            f.result()
+        get_registry().counter("relay_relabel_parallel")
+    return dist, parent
 
 
 #: Hybrid sparse-path budgets: a superstep takes the gather path when the
@@ -2142,18 +2248,27 @@ class RelayEngine:
             state, *self._dense_step_operands()
         )
 
-    def _to_result(self, state, source: int) -> BfsResult:
-        rg = self.relay_graph
-        dist = np.asarray(state.dist)[rg.old2new]
-        if self.expansion == "mxu":
-            # The mxu arm's parent VALUES are already ORIGINAL ids (the
-            # expansion's min-key candidates) — only the index space
-            # needs the relabel gather.
-            parent = np.asarray(state.parent)[rg.old2new].copy()
-        else:
-            parent = slots_to_parent(np.asarray(state.parent), rg.src_l1)[
-                rg.old2new
-            ]
+    def relabel(self, state, workers: int | None = None):
+        """A fetched relabeled-space state's ``(dist, parent)`` in
+        ORIGINAL id space (one search or a batch), through
+        :func:`relabel_to_original`.  The mxu arm's parent VALUES are
+        already original ids (the expansion's min-key candidates), so
+        only the gather arm maps slots."""
+        o2n = getattr(self, "_old2new_intp", None)
+        if o2n is None:
+            # Once per engine: numpy would otherwise copy the int32
+            # indices to intp on every gather.
+            o2n = self._old2new_intp = np.asarray(
+                self.relay_graph.old2new, dtype=np.intp
+            )
+        return relabel_to_original(
+            state.dist, state.parent, o2n, self.relay_graph.src_l1,
+            slots=self.expansion != "mxu", workers=workers,
+        )
+
+    def _to_result(self, state, source: int,
+                   workers: int | None = None) -> BfsResult:
+        dist, parent = self.relabel(state, workers)
         parent[source] = source  # init wrote the relabeled id at the source
         return BfsResult(dist=dist, parent=parent, num_levels=int(state.level))
 
@@ -2355,8 +2470,9 @@ class RelayEngine:
                         )
                     )
                 get_registry().counter("relay_unpacked_reruns")
-            with obs_span("relay.relabel"):
-                result = self._to_result(state, source)
+            workers = relabel_workers(1, rg.num_vertices)
+            with obs_span("relay.relabel", workers=workers):
+                result = self._to_result(state, source, workers)
         get_registry().counter("relay_searches")
         return result
 
@@ -2853,14 +2969,9 @@ class RelayEngine:
                         )
                     )
                 get_registry().counter("relay_unpacked_reruns")
-            with obs_span("relay.relabel"):
-                dist = np.asarray(state.dist)[:, rg.old2new]
-                if self.expansion == "mxu":
-                    parent = np.asarray(state.parent)[:, rg.old2new].copy()
-                else:
-                    parent = slots_to_parent(
-                        np.asarray(state.parent), rg.src_l1
-                    )[:, rg.old2new]
+            workers = relabel_workers(sources.shape[0], rg.num_vertices)
+            with obs_span("relay.relabel", workers=workers):
+                dist, parent = self.relabel(state, workers)
                 rows = np.arange(sources.shape[0])
                 # init wrote relabeled ids at sources
                 parent[rows, sources] = sources
@@ -3135,16 +3246,7 @@ class SuperstepRunner:
             from ..ops.relay import unpack_std
 
             rg = self._relay.relay_graph
-            dist = np.asarray(state.dist)[self._old2new]
-            if self._relay.expansion == "mxu":
-                # mxu-arm parent VALUES are already original ids (the
-                # expansion's min-key candidates) — slot-mapping them
-                # would gather nonsense through src_l1.
-                parent = np.asarray(state.parent)[self._old2new].copy()
-            else:
-                parent = slots_to_parent(
-                    np.asarray(state.parent), rg.src_l1
-                )[self._old2new]
+            dist, parent = self._relay.relabel(state)
             fbits = np.asarray(
                 unpack_std(jnp.asarray(state.fwords), rg.vr)
             ).astype(bool)[self._old2new]

@@ -290,7 +290,8 @@ def test_engine_spans_and_counters(tmp_path):
     eng.run_multi(sources)
     obs_spans.drain_events()
     reg = obs_registry.get_registry()
-    names = ("relay_searches", "relay_unpacked_reruns", "relay_fetch_bytes")
+    names = ("relay_searches", "relay_unpacked_reruns", "relay_fetch_bytes",
+             "relay_relabel_parallel")
     before = {k: reg.count(k) for k in names}
     out = {}
 
@@ -310,8 +311,12 @@ def test_engine_spans_and_counters(tmp_path):
     # Each fetch carries at least the dist and parent words of its trees.
     for e, trees in zip(fetched, (1, 1, 3, 3)):
         assert e["args"]["bytes"] >= trees * 2 * 4 * 70
+    # 70 and 210 values relabel inline: one worker, no parallel count.
     assert moved == {"relay_searches": 1 + 3, "relay_unpacked_reruns": 2,
-                     "relay_fetch_bytes": sum(e["args"]["bytes"] for e in fetched)}
+                     "relay_fetch_bytes": sum(e["args"]["bytes"] for e in fetched),
+                     "relay_relabel_parallel": 0}
+    assert [e["args"] for e in snapshot_events()
+            if e["name"] == "relay.relabel"] == [{"workers": 1}] * 2
     ev = {(e["name"], e["id"]): e for e in snapshot_events()}
     by_id = {e["id"]: e["name"] for e in ev.values()}
     for (name, _), e in ev.items():
@@ -321,6 +326,36 @@ def test_engine_spans_and_counters(tmp_path):
             assert by_id[e["parent"]] in ("relay.search", "relay.batch")
     assert [e["args"] for e in snapshot_events()
             if e["name"] == "relay.batch"] == [{"batch": 3}]
+
+
+def test_relabel_span_carries_workers_and_parallel_counter(monkeypatch):
+    """With the relabel split over threads, ``relay.relabel`` names the
+    threads it ran on and ``relay_relabel_parallel`` moves by one per
+    relabel; at the graph's own size it runs inline and moves by none."""
+    import importlib
+
+    from bfs_tpu.models.bfs import RelayEngine
+
+    bfs_mod = importlib.import_module("bfs_tpu.models.bfs")
+    eng = RelayEngine(rmat_graph(7, 8, seed=3))
+    sources = np.array([0, 5, 9], np.int32)
+    reg = obs_registry.get_registry()
+    eng.run(0)
+    eng.run_multi(sources)
+    obs_spans.drain_events()
+    before = reg.count("relay_relabel_parallel")
+    inline = (eng.run(1), eng.run_multi(sources))
+    assert reg.count("relay_relabel_parallel") == before
+    monkeypatch.setattr(bfs_mod, "RELABEL_INLINE_MAX", 16)
+    monkeypatch.setattr(bfs_mod, "_host_cores", lambda: 3)
+    split = (eng.run(1), eng.run_multi(sources))
+    assert reg.count("relay_relabel_parallel") == before + 2
+    assert [e["args"] for e in snapshot_events()
+            if e["name"] == "relay.relabel"] == [{"workers": 1}] * 2 + [
+                {"workers": 3}] * 2
+    for a, b in zip(inline, split):
+        assert a.dist.tobytes() == b.dist.tobytes()
+        assert a.parent.tobytes() == b.parent.tobytes()
 
 
 def test_engine_queues_the_host_copy_before_the_wait(monkeypatch):
