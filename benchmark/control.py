@@ -5,10 +5,11 @@ out as not correct.
     python3 benchmark/control.py --workload g500-s20.single \\
         --seeds 11,12,13 --seconds 5
 
-The configurations guarantee exact answers.  Each driver's ``control()``
-breaks that guarantee the way a change that trades exactness for speed
-would (``closed_search``: each search stops one level short of its last
-level, as a level cap set too low would).
+The configurations guarantee exact answers.  The control comes from the
+driver that the cell's traffic mix names: its ``control()`` breaks that
+guarantee the way a change that trades exactness for speed would (for
+``closed_search``, each search stops one level short of its last level,
+as a level cap set too low would).
 
 Each seed runs the whole cell (set-up, a short window at the cell's own
 load, the check) with the control in the program's place, and prints the

@@ -11,16 +11,15 @@ Traffic parameters: ``keys``, ``batch``, ``structure_seed`` (see
 from __future__ import annotations
 
 import gc
-import time
 from types import SimpleNamespace
 
 import numpy as np
 
 from benchmark import harness, traffic
 from benchmark.reference import UNREACHED, Reference
-from benchmark.runs import (Context, Reservoir, Run, Stages, annotate,
-                            build_graph, engine_arms, layout_shapes)
-from benchmark.trace import traced_window
+from benchmark.runs import (Context, Reservoir, Run, Stages, build_graph,
+                            closed_window, engine_arms, layout_shapes,
+                            traversed_edges)
 
 
 def drive(ctx: Context, make_engine=None) -> Run:
@@ -61,43 +60,16 @@ def drive(ctx: Context, make_engine=None) -> Run:
     for k in range(int(params["warmup_requests"])):
         ask(requests[k % len(requests)])
     stages.mark("warmup")
-    before = ctx.meter.snapshot()
-    run.facts["setup_compile"] = before
     run.facts["setup_stages"] = stages.seconds
-    run.setup_s = time.perf_counter() - ctx.t_start
 
     sample = Reservoir(params["check_trees"],
                        traffic.rng(ctx.seed, traffic.SAMPLE))
-    sent = np.zeros(len(requests), np.int64)
-    i = 0
-    t0 = time.perf_counter()
-    with traced_window(ctx.trace_dir):
-        while True:
-            sources = requests[i % len(requests)]
-            with annotate("bench.request"):
-                dist, parent = ask(sources)
-            for t, root in enumerate(sources.tolist()):
-                slot = sample.wants()
-                if slot is not None:
-                    sample.items[slot] = (root, dist[t].copy(), parent[t].copy())
-            sent[i % len(requests)] += 1
-            i += 1
-            if time.perf_counter() - t0 >= ctx.seconds:
-                break
-    run.window_s = time.perf_counter() - t0
-    harness.note("window_compiles",
-                 harness.CompileMeter.since(before, ctx.meter.snapshot()))
-    run.requests = i
-    run.searches = i * requests.shape[1]
-    run.attempted = run.searches
-    run.device = harness.device_info(ctx.devices)
-    del eng, rg, dist, parent
+    sent = closed_window(ctx, run, requests, ask, sample)
+    del ask, call, eng, rg
     gc.collect()
 
     ref = Reference(n, src, dst)
-    per_request = ref.traversed_edges(requests.reshape(-1)).reshape(
-        requests.shape).sum(axis=1)
-    run.traversed_edges = int((per_request * sent).sum())
+    run.traversed_edges = traversed_edges(ref, requests, sent)
     run.checks = check_trees(ref, sample.items)
     return run
 

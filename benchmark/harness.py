@@ -16,6 +16,15 @@ entries, and edits no file that is there:
 - a metric, end to end or per layer, is ``benchmark/metrics/<name>.py``,
   whose ``read(run)`` returns the number or ``None`` when the run holds
   nothing to read.
+
+A new kernel's driver (an SSSP, another engine) writes only what is its
+own: its engine, the ``ask(sources)`` that calls it, its check against
+the reference and its ``control()``.  The closed-loop window
+(``runs.closed_window``) and the Graph500 edge count
+(``runs.traversed_edges``) are shared, so ``teps`` means the same in
+every cell, and a reader that does not depend on the program (``teps``,
+``device_idle_share.traversal``) reads any driver's run: a cell joins
+it by its entry's ``workloads`` list alone.
 """
 
 from __future__ import annotations

@@ -9,7 +9,15 @@ memory peak read) frees the program's state and checks the answers
 against ``reference.py``.  It returns a :class:`Run`, from which the
 metric readers of ``benchmark/metrics/`` take their numbers.  Its
 ``control()`` returns the ``swap`` that puts the control of the check in
-the program's place (``control.py``).
+the program's place (``control.py``).  It may define ``tiny(params) ->
+params``, the cut of its traffic mix that the CPU tests run.
+
+A closed loop of searches, whatever kernel it drives, shares its window
+and its edge count: the driver writes its engine, an ``ask(sources) ->
+(dist[B, n], parent[B, n])`` into it, its check and its control, and
+hands ``ask`` to :func:`closed_window`; :func:`traversed_edges` then
+gives the Graph500 edge count of the searches the window completed, the
+one that ``teps`` reads.
 """
 
 from __future__ import annotations
@@ -19,6 +27,9 @@ from dataclasses import dataclass, field
 from typing import Callable
 
 import numpy as np
+
+from benchmark import harness
+from benchmark.trace import traced_window
 
 
 @dataclass
@@ -87,6 +98,56 @@ class Reservoir:
             return j
         r = int(self.gen.integers(0, j + 1))
         return r if r < self.size else None
+
+
+def closed_window(ctx: Context, run: Run, requests: np.ndarray,
+                  ask: Callable, sample: Reservoir) -> np.ndarray:
+    """The measured window of a closed loop of searches: one caller sends
+    ``requests[i % R]`` (int32[R, B] keys) through ``ask`` as soon as the
+    last request has returned, until ``ctx.seconds`` have passed, and
+    offers each tree to ``sample`` as a ``(root, dist, parent)`` copy made
+    inside the window.  Set-up ends where the window starts:
+    ``run.setup_s`` and the set-up's compiles are taken here.  Fills the
+    window's seconds, requests, searches and attempts, notes the compiles
+    inside the window, and reads the device's memory peak before the
+    driver frees its state.  Returns how often each request was sent."""
+    before = ctx.meter.snapshot()
+    run.facts["setup_compile"] = before
+    run.setup_s = time.perf_counter() - ctx.t_start
+    sent = np.zeros(len(requests), np.int64)
+    i = 0
+    t0 = time.perf_counter()
+    with traced_window(ctx.trace_dir):
+        while True:
+            sources = requests[i % len(requests)]
+            with annotate("bench.request"):
+                dist, parent = ask(sources)
+            for t, root in enumerate(sources.tolist()):
+                slot = sample.wants()
+                if slot is not None:
+                    sample.items[slot] = (root, dist[t].copy(), parent[t].copy())
+            sent[i % len(requests)] += 1
+            i += 1
+            if time.perf_counter() - t0 >= ctx.seconds:
+                break
+    run.window_s = time.perf_counter() - t0
+    harness.note("window_compiles",
+                 harness.CompileMeter.since(before, ctx.meter.snapshot()))
+    run.requests = i
+    run.searches = i * requests.shape[1]
+    run.attempted = run.searches
+    run.device = harness.device_info(ctx.devices)
+    return sent
+
+
+def traversed_edges(ref, requests: np.ndarray, sent: np.ndarray) -> int:
+    """The Graph500 traversed edges of the searches a closed window
+    completed: each key's count (``Reference.traversed_edges``, the edges
+    of its component, for BFS and SSSP alike) times how often its request
+    was sent."""
+    per_request = ref.traversed_edges(requests.reshape(-1)).reshape(
+        requests.shape).sum(axis=1)
+    return int((per_request * sent).sum())
 
 
 class Stages:

@@ -23,32 +23,43 @@ class NullMeter:
                 "cache_misses": 0, "missed": []}
 
 
-def make_root(path: str, scale: int = 7) -> str:
-    """A checkout root holding the benchmark, its graphs cut to
-    ``scale`` and its traffic cut to match."""
-    shutil.copytree(os.path.join(REPO, "benchmark"),
+def make_root(path: str, scale: int = 7, source: str = REPO) -> str:
+    """A checkout root holding the benchmark of ``source``, cut to a tiny
+    size.  Only what a cell's files declare is cut: a configuration's
+    ``graph.scale`` to ``scale``; a traffic mix's ``batch`` to at most 16,
+    its ``keys`` to four batches and its ``check_trees`` to at most 8;
+    then whatever its driver's optional ``tiny(params) -> params`` cuts."""
+    from benchmark import harness
+
+    shutil.copytree(os.path.join(source, "benchmark"),
                     os.path.join(path, "benchmark"),
                     ignore=shutil.ignore_patterns("__pycache__"))
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        bench = json.load(f)
+    shutil.copy(os.path.join(source, "BENCHMARK.json"), path)
+    bench = harness.load_benchmark(path)
     for entry in bench["configs"]:
         cfg_path = os.path.join(path, entry["file"])
         with open(cfg_path) as f:
             cfg = json.load(f)
-        cfg["graph"]["scale"] = scale
-        with open(cfg_path, "w") as f:
-            json.dump(cfg, f)
-    for cell in bench["workloads"]:
-        t_path = os.path.join(path, "benchmark", "traffic", cell["traffic"] + ".json")
+        if "scale" in cfg.get("graph", {}):
+            cfg["graph"]["scale"] = scale
+            with open(cfg_path, "w") as f:
+                json.dump(cfg, f)
+    for name in sorted({cell["traffic"] for cell in bench["workloads"]}):
+        t_path = os.path.join(path, "benchmark", "traffic", name + ".json")
         with open(t_path) as f:
             params = json.load(f)
-        params["batch"] = min(params["batch"], 16)
-        params["keys"] = 4 * params["batch"]
-        params["check_trees"] = min(params["check_trees"], 8)
+        if "batch" in params:
+            params["batch"] = min(params["batch"], 16)
+        if "keys" in params:
+            params["keys"] = 4 * params.get("batch", 1)
+        if "check_trees" in params:
+            params["check_trees"] = min(params["check_trees"], 8)
+        tiny = getattr(harness.load_plugin("drivers", params["driver"], path),
+                       "tiny", None)
+        if tiny is not None:
+            params = tiny(params)
         with open(t_path, "w") as f:
             json.dump(params, f)
-    with open(os.path.join(path, "BENCHMARK.json"), "w") as f:
-        json.dump(bench, f)
     return path
 
 

@@ -6,9 +6,10 @@ from __future__ import annotations
 import hashlib
 import json
 import os
+import shutil
 
 import pytest
-from harness_fixtures import run_cell, tiny_root  # noqa: F401 (fixtures)
+from harness_fixtures import REPO, make_root, run_cell, tiny_root  # noqa: F401 (fixtures)
 
 from benchmark import harness
 from benchmark.runs import Run
@@ -70,6 +71,54 @@ def drive(ctx):
     wrong = sum(int(np.count_nonzero(d != w)) for (_, d), w in zip(got, want))
     run.checks = {"wrong_distances": {"value": wrong, "limit": 0}}
     return run
+'''
+
+#: A second kernel's driver, of a kind other than ``closed_search``: a
+#: closed loop through the shared window, with the plain reference in the
+#: engine's place; its control stops each search one level short.
+PLAIN_DRIVER = '''
+import numpy as np
+
+from benchmark import traffic
+from benchmark.reference import UNREACHED, Reference
+from benchmark.runs import (Reservoir, Run, build_graph, closed_window,
+                            traversed_edges)
+
+
+def drive(ctx, short=False):
+    n, src, dst = build_graph(ctx)
+    engine = Reference(n, src, dst)
+    requests = traffic.closed_requests(
+        ctx.params, np.bincount(src, minlength=n), ctx.seed)
+
+    def ask(sources):
+        dist = engine.distances(sources)
+        for d in dist:
+            last = d[d != UNREACHED].max()
+            if short and last > 0:
+                d[d == last] = UNREACHED
+        return dist, np.stack([engine.parents(d, int(s))
+                               for s, d in zip(sources, dist)])
+
+    ask(requests[0])
+    run = Run(kind="fixture_plain")
+    sample = Reservoir(ctx.params["check_trees"],
+                       traffic.rng(ctx.seed, traffic.SAMPLE))
+    sent = closed_window(ctx, run, requests, ask, sample)
+    ref = Reference(n, src, dst)
+    run.traversed_edges = traversed_edges(ref, requests, sent)
+    wrong = bad = 0
+    for root, dist, parent in sample.items:
+        w, b = ref.tree_faults(root, ref.distances([root])[0], dist, parent)
+        wrong, bad = wrong + w, bad + b
+    run.checks = {"wrong_distances": {"value": wrong, "limit": 0},
+                  "bad_parents": {"value": bad, "limit": 0},
+                  "unchecked": {"value": 0 if sample.items else 1, "limit": 0}}
+    return run
+
+
+def control():
+    return {"short": True}
 '''
 
 LATENCY_METRIC = '''
@@ -216,9 +265,119 @@ def test_a_reader_with_nothing_to_read_is_left_out():
     assert harness.read_metrics(entries, run) == {}
 
 
-@pytest.mark.parametrize("workload", ["g500-s20.single", "g500-s20.multi64"])
+@pytest.mark.parametrize(
+    "workload", [w["name"] for w in harness.load_benchmark()["workloads"]])
 def test_each_cell_has_its_metrics(workload):
     bench = harness.load_benchmark()
     for trace in (False, True):
         for m in harness.cell_metrics(bench, workload, trace):
             assert callable(harness.load_reader(m["name"]))
+
+
+def _join(root: str, workload: str, *metrics: str) -> None:
+    """Adds ``workload`` to the ``workloads`` lists of the named metrics."""
+    path = os.path.join(root, "BENCHMARK.json")
+    with open(path) as f:
+        bench = json.load(f)
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if m["name"] in metrics:
+            m["workloads"].append(workload)
+    with open(path, "w") as f:
+        json.dump(bench, f)
+
+
+def test_a_second_kernel_reports_teps_as_files(tiny_root, run_cell):
+    """A closed loop of another kind than ``closed_search``, on a graph
+    maker without a scale, added as new files: it runs through the shared
+    window, joins ``teps`` and the idle share by their ``workloads`` lists
+    alone, and its control comes out not correct, with no file of the
+    benchmark edited."""
+    before = _digests(tiny_root)
+    _write(tiny_root, "benchmark/graphs/fixture_ring.py", RING_GRAPH)
+    _write(tiny_root, "benchmark/drivers/fixture_plain.py", PLAIN_DRIVER)
+    _write(tiny_root, "benchmark/traffic/fixture_plain.json", json.dumps(
+        {"driver": "fixture_plain", "keys": 8, "batch": 2,
+         "structure_seed": 3, "check_trees": 4}))
+    _write(tiny_root, "benchmark/configs/fixture-ring.json", json.dumps(
+        {"name": "fixture-ring", "graph": {"generator": "fixture_ring",
+                                           "vertices": 256}}))
+    _add_entries(
+        tiny_root,
+        configs=[{"name": "fixture-ring", "source": "https://example.org",
+                  "file": "benchmark/configs/fixture-ring.json",
+                  "reduced": [], "why": "fixture"}],
+        workloads=[{"name": "fixture.plain", "config": "fixture-ring",
+                    "traffic": "fixture_plain", "chips": 1, "why": "fixture"}])
+    _join(tiny_root, "fixture.plain", "teps", "device_idle_share.traversal")
+    bench = harness.load_benchmark(tiny_root)
+    assert [m["name"] for m in harness.cell_metrics(
+        bench, "fixture.plain", True)] == ["device_idle_share.traversal"]
+
+    res = run_cell("fixture.plain", seconds=0.3)
+    assert res["correct"], res["checks"]
+    assert res["attempted"] > 0 and res["attempted"] % 2 == 0
+    assert set(res["metrics"]) == {"teps", "setup_s"}
+    assert res["metrics"]["teps"]["value"] > 0
+    ctl = run_cell("fixture.plain", seconds=0.3,
+                   **harness.load_plugin("drivers", "fixture_plain",
+                                         tiny_root).control())
+    assert not ctl["correct"]
+    assert ctl["checks"]["wrong_distances"]["value"] > 0
+    after = _digests(tiny_root)
+    assert {p: after[p] for p in before} == before
+
+
+def test_the_bfs_cells_report_teps_and_setup():
+    bench = harness.load_benchmark()
+    for workload in ("g500-s20.single", "g500-s20.multi64"):
+        assert {m["name"] for m in harness.cell_metrics(
+            bench, workload, False)} == {"teps", "setup_s"}
+
+
+def _json(root: str, rel: str) -> dict:
+    with open(os.path.join(root, rel)) as f:
+        return json.load(f)
+
+
+def test_make_root_cuts_only_what_a_cell_declares(tmp_path):
+    """A configuration without ``graph.scale`` and a traffic mix without
+    ``batch``, ``keys`` or ``check_trees`` come through untouched; a
+    driver's ``tiny`` makes its own cut; the BFS cells are cut as before."""
+    repo = str(tmp_path / "repo")
+    shutil.copytree(os.path.join(REPO, "benchmark"),
+                    os.path.join(repo, "benchmark"),
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(os.path.join(REPO, "BENCHMARK.json"), repo)
+    ring = {"name": "fixture-ring",
+            "graph": {"generator": "fixture_ring", "vertices": 256}}
+    bursts = {"driver": "fixture_bursts", "burst": 4, "period_s": 0.2}
+    sweep = {"driver": "fixture_sweep", "sources": 64}
+    _write(repo, "benchmark/graphs/fixture_ring.py", RING_GRAPH)
+    _write(repo, "benchmark/drivers/fixture_bursts.py", BURST_DRIVER)
+    _write(repo, "benchmark/drivers/fixture_sweep.py",
+           "def tiny(params):\n    return {**params, 'sources': 2}\n")
+    _write(repo, "benchmark/configs/fixture-ring.json", json.dumps(ring))
+    _write(repo, "benchmark/traffic/fixture_bursts.json", json.dumps(bursts))
+    _write(repo, "benchmark/traffic/fixture_sweep.json", json.dumps(sweep))
+    _add_entries(
+        repo,
+        configs=[{"name": "fixture-ring", "source": "https://example.org",
+                  "file": "benchmark/configs/fixture-ring.json",
+                  "reduced": [], "why": "fixture"}],
+        workloads=[{"name": "fixture.bursts", "config": "fixture-ring",
+                    "traffic": "fixture_bursts", "chips": 1, "why": "fixture"},
+                   {"name": "fixture.sweep", "config": "fixture-ring",
+                    "traffic": "fixture_sweep", "chips": 1, "why": "fixture"}])
+
+    root = make_root(str(tmp_path / "root"), source=repo)
+    assert _json(root, "benchmark/configs/fixture-ring.json") == ring
+    assert _json(root, "benchmark/traffic/fixture_bursts.json") == bursts
+    assert _json(root, "benchmark/traffic/fixture_sweep.json") == {
+        **sweep, "sources": 2}
+    g500 = _json(REPO, "benchmark/configs/graph500-bfs-s20.json")
+    g500["graph"]["scale"] = 7
+    assert _json(root, "benchmark/configs/graph500-bfs-s20.json") == g500
+    for name, cut in [("graph500_keys", {"batch": 1, "keys": 4, "check_trees": 8}),
+                      ("multi_batch", {"batch": 16, "keys": 64, "check_trees": 8})]:
+        rel = f"benchmark/traffic/{name}.json"
+        assert _json(root, rel) == {**_json(REPO, rel), **cut}

@@ -161,3 +161,23 @@ def test_recorded_v5e_trace_has_nothing_to_read():
     assert sp.idle_seconds(t) is None and sp.superstep_seconds(t) is None
     names = sorted(set(SINGLE_METRICS + MULTI_METRICS))
     assert read_all(run_of(t), names) == {}
+
+
+@pytest.mark.parametrize("kind", ["closed_search", "fixture_plain"])
+def test_driver_neutral_readers(kind):
+    """``teps`` and the device idle share read what they read before for
+    a ``closed_search`` run, and the same for any other driver's run."""
+    t = tr.from_profile(profile())
+    run = Run(kind=kind, searches=2, requests=2, traversed_edges=3000,
+              window_s=1.5, trace=t, facts={"batch": 1})
+    got = read_all(run, ["teps", "device_idle_share.traversal"])
+    assert got["teps"]["value"] == 3000 / 1.5
+    assert got["device_idle_share.traversal"]["value"] == t.idle_share == \
+        pytest.approx(45.0)
+
+
+def test_searches_without_counted_edges_read_no_teps():
+    """A run that completed searches but counted no traversed edges (an
+    open loop of bursts, say) has no ``teps`` to read."""
+    run = Run(kind="fixture_bursts", searches=16, requests=16, window_s=0.6)
+    assert read_all(run, ["teps"]) == {}
