@@ -8,12 +8,13 @@ every device then computes identical state updates — no further
 collectives, the replicated-carry contract the BFS mesh programs
 established (version-spanning via :mod:`bfs_tpu.parallel.compat`).
 
-SSSP needs no weight operand plumbing: weights are a hash of the
-endpoints (:func:`bfs_tpu.algo.substrate.edge_weights`), so each mesh
-body recomputes its own shard's weights from the edge block it already
-holds — re-sharding can never misalign them.  The exit-time parent
-canonicalization runs OUTSIDE the mesh on the flat edge arrays (the
-replicated final dists make it shard-count-independent by construction).
+Sharded SSSP keeps the endpoint-hash weights
+(:func:`bfs_tpu.algo.substrate.edge_weights`): each mesh body recomputes
+its own shard's weights from the edge block it already holds, so
+re-sharding can never misalign them (stored weights run on one device,
+:func:`bfs_tpu.algo.sssp.sssp`).  The exit-time parent pass runs OUTSIDE
+the mesh on the flat edge arrays (the replicated final dists make it
+shard-count-independent by construction).
 """
 
 from __future__ import annotations
@@ -34,7 +35,9 @@ from .cc import CcResult, CcState, cc_superstep, init_cc_state
 from .sssp import (
     SsspResult,
     SsspState,
-    _finish,
+    _converged,
+    _hash_weights,
+    _parents_and_fetch,
     _rounds_cap,
     init_sssp_state,
     sssp_superstep,
@@ -122,13 +125,16 @@ def sssp_sharded(
     the mesh's graph-axis extent; results are bit-identical to the
     single-device :func:`bfs_tpu.algo.sssp.sssp` unpacked arm — the pmin
     merge commutes with the segmented min."""
+    if graph.weight is not None:
+        raise ValueError("sharded SSSP runs on the endpoint hash; a graph "
+                         "with stored weights runs on one device (sssp)")
     if mesh is None:
         mesh = make_mesh(graph=num_shards, batch=1)
     n_shards = mesh.shape[GRAPH_AXIS]
     dg = build_device_graph(graph, num_shards=n_shards, block=block)
     v = dg.num_vertices
     delta_i = resolve_delta(delta)
-    cap = _rounds_cap(v, max_weight, max_rounds)
+    cap = _rounds_cap(v, max_rounds)
     state = _sssp_sharded_fused(
         jnp.asarray(dg.src), jnp.asarray(dg.dst), jnp.int32(source),
         mesh=mesh, num_vertices=v, max_weight=max_weight,
@@ -136,12 +142,16 @@ def sssp_sharded(
     )
     flat_src = jnp.asarray(np.ascontiguousarray(dg.src.reshape(-1)))
     flat_dst = jnp.asarray(np.ascontiguousarray(dg.dst.reshape(-1)))
-    dist, parent = _finish(
-        state.dist, flat_src, flat_dst, source, v + 1, max_weight
+    # The shards, laid end to end, are not dst-sorted as a whole: no
+    # ``ends``, so the parent pass combines by the scatter.
+    dist, parent, rounds, changed = _parents_and_fetch(
+        state.dist, state.rounds, state.changed, flat_src, flat_dst,
+        _hash_weights(flat_src, flat_dst, max_weight=max_weight),
+        None, source, v + 1,
     )
+    _converged(changed, rounds, max_rounds, source)
     return SsspResult(
-        dist=dist[:v], parent=parent[:v],
-        rounds=int(jax.device_get(state.rounds)),
+        dist=dist[:v], parent=parent[:v], rounds=rounds,
         max_weight=max_weight, delta=delta_i, packed=False,
     )
 

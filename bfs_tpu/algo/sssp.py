@@ -2,42 +2,70 @@
 
 The BFS superstep machinery instantiated on the ``sssp`` semiring row
 (:data:`bfs_tpu.algo.substrate.SEMIRINGS`): per active edge the
-contribution is ``dist[src] + w(src, dst)`` instead of ``src``, the
-combine is the SAME segmented min (:func:`bfs_tpu.ops.relax.combine_min`),
-and the apply keeps the min per destination.  Because weights are a hash
-of the endpoints (:func:`bfs_tpu.algo.substrate.edge_weights`), every
-layout — dst-sorted, padded, round-robin sharded — recomputes its own
-weights from the edge arrays it already holds.
+contribution is ``dist[src] + w`` instead of ``src``, the combine is the
+SAME segmented min (:func:`bfs_tpu.ops.relax.combine_min`), and the apply
+keeps the min per destination.
+
+**Weights.**  The weights are an operand of the program, aligned with
+the dst-sorted edge arrays, and the state is typed by them: int32
+distances with ``INT32_MAX`` for unreached under integer weights, float32
+distances with ``+inf`` under float weights (Graph500 kernel 3), the
+bucket threshold and delta in the same dtype.  A graph with stored
+weights (``Graph.weight``) hands them over as they are; a graph without
+fills the operand with the endpoint hash
+(:func:`bfs_tpu.algo.substrate.edge_weights`, integers in
+``[1, max_weight]``).  Every addition is the dtype's own: a float
+distance is the float32 sum of its path's weights, left to right.
 
 **Delta-stepping.**  The loop carry includes a bucket ``threshold`` T:
 only dirty vertices with ``dist < T`` relax (the current bucket).  When
 the bucket drains with dirty work remaining, T jumps to
-``min(dist[dirty]) + delta`` — the classic bucket advance, here one
+``min(dist[dirty]) + delta`` (above ``min(dist[dirty])`` even where delta
+is below half a float ulp) — the classic bucket advance, here one
 ``where`` on a carried scalar, no host round-trip.  ``delta=inf`` (env
 ``BFS_TPU_SSSP_DELTA``) degenerates to one bucket = plain frontier
 Bellman-Ford; any delta yields the same fixpoint (tests pin this), it
-only reshapes the superstep schedule, trading rounds against wasted
-long-edge relaxations exactly as in the CPU algorithm.
+only reshapes the superstep schedule.  Each superstep sweeps every edge,
+so a search costs about supersteps x E whatever the bucket, and one
+bucket takes the fewest supersteps: float weights default to it.
 
-**Canonical parents.**  Parents are NOT carried through the loop: the
-unique shortest-distance fixpoint determines them after the fact.  One
-exit-time canonicalization pass (:func:`_sssp_parents`) takes, per
-reached vertex, the MINIMUM u among in-edges with
-``dist[u] + w(u, v) == dist[v]`` — the same ``combine_min`` — so every
-engine arm (fused, segmented, sharded, packed) produces bit-identical
-parents, and the host Dijkstra oracle applies the identical rule.
+**One gather a superstep.**  On the TPU a per-edge gather or scatter is
+a per-element loop, and it sets the superstep's cost.  The frontier
+folds into the gathered distance (the identity off the frontier), and
+the combine takes its scan form (:func:`bfs_tpu.ops.relax.combine_min`
+with :func:`bfs_tpu.ops.relax.dst_ends`, computed once a search), which
+replaces the scatter over every edge with dense row scans and one
+gather per vertex.
 
-**Packed arm.**  For ``V < 2^16 - 1`` the carry word fuses
-``dist:16 | parent:16`` (the BFS ``level:6|parent:26`` word widened for
-valued distances): candidates travel as packed words through ONE uint32
-``segment_min`` and the merge is gated on STRICT distance improvement, so
-the packed arm's frontier schedule and round count are bit-identical to
-the unpacked arm.  Distances are clamped to 0xFFFE in flight; a final
-distance hitting the clamp reports truncation and the caller re-runs
-unpacked — the same detect-and-fall-back contract as the >62-level BFS
-cap (``packed_truncated``).  The word's parent bits are a provisional
-last-improver (diagnostic only); the exit canonicalization pass is
-authoritative on both arms.
+**Parents.**  Parents are NOT carried through the loop: the
+shortest-distance fixpoint determines them after the fact, in one
+exit-time pass (:func:`_sssp_parents`) shared by every arm.  A vertex
+takes the least ``u`` among its tight in-edges (``dist[u] + w ==
+dist[v]``) with ``dist[u] < dist[v]``; where every tight in-edge ties
+(a zero weight, or a float weight under half an ulp of the distance),
+it takes the least tied ``u`` whose own parent is already fixed, in
+rounds out from the fixed set.  Distances fall along a strict parent and
+fixing rounds fall along a tied one, so the parents form a tree rooted
+at the source.  Integer weights >= 1 never tie, and the rule is then
+the canonical min optimal predecessor the host Dijkstra oracle applies.
+
+**Packed arm.**  For integer weights and ``V < 2^16 - 1`` the carry word
+fuses ``dist:16 | parent:16`` (the BFS ``level:6|parent:26`` word widened
+for valued distances): candidates travel as packed words through ONE
+uint32 ``segment_min`` and the merge is gated on STRICT distance
+improvement, so the packed arm's frontier schedule and round count are
+bit-identical to the unpacked arm.  Distances are clamped to 0xFFFE in
+flight; a final distance hitting the clamp reports truncation and the
+caller re-runs unpacked — the same detect-and-fall-back contract as the
+>62-level BFS cap (``packed_truncated``).  The word's parent bits are a
+provisional last-improver (diagnostic only); the exit pass is
+authoritative on both arms.  Float weights never take it.
+
+**Spans and counters.**  :func:`sssp_device` records ``sssp.search``
+around ``sssp.launch`` (dispatch of the fused program), ``sssp.parents``
+(dispatch of the parent pass), ``sssp.device_wait`` and ``sssp.fetch``
+(``bytes``), and bumps ``sssp_searches`` and ``sssp_supersteps`` (the
+``rounds`` it fetches anyway).
 """
 
 from __future__ import annotations
@@ -52,7 +80,9 @@ import numpy as np
 
 from ..analysis.runtime import traced
 from ..graph.csr import Graph, NO_PARENT, build_device_graph
-from ..ops.relax import INT32_MAX, combine_min
+from ..obs.registry import get_registry
+from ..obs.spans import span as obs_span
+from ..ops.relax import INT32_MAX, combine_min, dst_ends
 from .substrate import DEFAULT_MAX_WEIGHT, edge_weights, resolve_delta
 
 #: Host-int mirror of the unreached sentinel for static saturation
@@ -73,14 +103,25 @@ def packed16_fits(num_vertices: int) -> bool:
     return int(num_vertices) < PACKED16_MAX_V
 
 
+def is_float(dtype) -> bool:
+    """Float weights (float32 distances) rather than integer ones."""
+    return np.issubdtype(dtype, np.floating)
+
+
+def unreached(dtype):
+    """The distance of an unreached vertex under weights of ``dtype``:
+    ``+inf`` for float weights, ``INT32_MAX`` for integer ones."""
+    return np.float32(np.inf) if is_float(dtype) else INT32_MAX
+
+
 class SsspState(NamedTuple):
     """Unpacked loop carry.  ``dirty`` marks vertices whose dist improved
     since they last relaxed their out-edges (the delta-stepping work
     set); ``threshold`` is the current bucket's exclusive upper bound."""
 
-    dist: jax.Array  # int32[V+1]; INT32_MAX = unreached; slot V inert
+    dist: jax.Array  # int32 or float32 [V+1]; unreached(dtype); slot V inert
     dirty: jax.Array  # bool[V+1]
-    threshold: jax.Array  # int32 scalar
+    threshold: jax.Array  # scalar of dist's dtype
     rounds: jax.Array  # int32 scalar: supersteps executed
     changed: jax.Array  # bool scalar: dirty work remains
 
@@ -96,13 +137,17 @@ class PackedSsspState(NamedTuple):
     changed: jax.Array
 
 
-def init_sssp_state(num_vertices: int, source, delta: int) -> SsspState:
+def init_sssp_state(
+    num_vertices: int, source, delta, dtype=jnp.int32
+) -> SsspState:
+    """The carry at the start of a search under weights of ``dtype``."""
     n = num_vertices + 1
+    dtype = jnp.dtype(dtype)
     source = jnp.asarray(source, dtype=jnp.int32)
-    dist = jnp.full((n,), INT32_MAX, dtype=jnp.int32).at[source].set(0)
+    dist = jnp.full((n,), unreached(dtype), dtype=dtype).at[source].set(0)
     dirty = jnp.zeros((n,), dtype=bool).at[source].set(True)
     return SsspState(
-        dist, dirty, jnp.int32(delta), jnp.int32(0), jnp.bool_(True)
+        dist, dirty, jnp.asarray(delta, dtype), jnp.int32(0), jnp.bool_(True)
     )
 
 
@@ -129,15 +174,32 @@ def packed16_dist(packed: jax.Array) -> jax.Array:
     return jnp.where(d16 == PACKED16_UNREACHED, INT32_MAX, d16)
 
 
+def _next_threshold(min_dirty: jax.Array, delta) -> jax.Array:
+    """The next bucket's exclusive bound, ``min_dirty + delta``, always
+    above ``min_dirty``.  Integers saturate (``min(.., MAX-delta)+delta``
+    keeps the all-buckets threshold finite: delta=inf lands exactly on
+    INT32_MAX); a float steps to the next float where delta is below half
+    an ulp of ``min_dirty``, so the bucket never comes out empty."""
+    if is_float(min_dirty.dtype):
+        return jnp.maximum(
+            min_dirty + jnp.float32(delta),
+            jnp.nextafter(min_dirty, jnp.float32(jnp.inf)),
+        )
+    return jnp.minimum(
+        min_dirty, jnp.int32(_INT32_MAX_HOST - delta)
+    ) + jnp.int32(delta)
+
+
 # bfs_tpu: hot traced
 def sssp_superstep(
     state: SsspState,
     src: jax.Array,
     dst: jax.Array,
     w: jax.Array,
-    delta: int,
+    delta,
     *,
     axis_name: str | None = None,
+    ends: jax.Array | None = None,
 ) -> SsspState:
     """One min-plus superstep: relax the current bucket's dirty vertices,
     then advance the bucket threshold iff it drained with work left.
@@ -147,12 +209,13 @@ def sssp_superstep(
     identical collective shape as the BFS sharded superstep, so the
     min-plus arm inherits the replicated-state contract unchanged."""
     n = state.dist.shape[0]
+    far = unreached(state.dist.dtype)
     frontier = state.dirty & (state.dist < state.threshold)
-    active = frontier[src]
-    # The sum may wrap where inactive (dist = INT32_MAX); those lanes are
-    # masked to the identity before the combine ever sees them.
-    sums = state.dist[src] + w
-    cand = combine_min(jnp.where(active, sums, INT32_MAX), dst, n)
+    # One gather per edge (the superstep's cost on the TPU): the frontier
+    # folds into the gathered distance as the identity, and a lane that
+    # gathers the identity keeps it (an integer sum would wrap there).
+    ds = jnp.where(frontier, state.dist, far)[src]
+    cand = combine_min(jnp.where(ds == far, far, ds + w), dst, n, ends)
     if axis_name is not None:
         cand = jax.lax.pmin(cand, axis_name)
     improved = cand < state.dist
@@ -160,13 +223,10 @@ def sssp_superstep(
     dirty = (state.dirty & ~frontier) | improved
     # Bucket advance: only when the bucket drained (no frontier at all)
     # and dirty work remains beyond the threshold.
-    min_dirty = jnp.min(jnp.where(dirty, dist, INT32_MAX))
-    # Saturating advance: min(.., MAX-delta)+delta keeps the final
-    # all-buckets threshold finite (delta=inf lands exactly on INT32_MAX).
+    min_dirty = jnp.min(jnp.where(dirty, dist, far))
     threshold = jnp.where(
-        ~frontier.any() & (min_dirty != INT32_MAX),
-        jnp.minimum(min_dirty, jnp.int32(_INT32_MAX_HOST - delta))
-        + jnp.int32(delta),
+        ~frontier.any() & (min_dirty != far),
+        _next_threshold(min_dirty, delta),
         state.threshold,
     )
     return SsspState(
@@ -183,6 +243,7 @@ def sssp_superstep_packed(
     delta: int,
     *,
     axis_name: str | None = None,
+    ends: jax.Array | None = None,
 ) -> PackedSsspState:
     """Packed twin: candidates travel as ``dist:16|parent:16`` words
     through one uint32 combine; the merge is strict on the DISTANCE field
@@ -198,7 +259,7 @@ def sssp_superstep_packed(
     )
     cand_word = (sums << 16) | src.astype(jnp.uint32)
     cand = combine_min(
-        jnp.where(active, cand_word, jnp.uint32(0xFFFFFFFF)), dst, n
+        jnp.where(active, cand_word, jnp.uint32(0xFFFFFFFF)), dst, n, ends
     )
     if axis_name is not None:
         cand = jax.lax.pmin(cand, axis_name)
@@ -214,8 +275,7 @@ def sssp_superstep_packed(
     min_dirty = jnp.min(dirty_dist)
     threshold = jnp.where(
         ~frontier.any() & (min_dirty != INT32_MAX),
-        jnp.minimum(min_dirty, jnp.int32(_INT32_MAX_HOST - delta))
-        + jnp.int32(delta),
+        _next_threshold(min_dirty, delta),
         state.threshold,
     )
     return PackedSsspState(
@@ -223,47 +283,85 @@ def sssp_superstep_packed(
     )
 
 
-@functools.partial(jax.jit, static_argnames=("num_segments", "max_weight"))
+@functools.partial(jax.jit, static_argnames=("num_segments",))
 @traced("algo.sssp_parents")
-def _sssp_parents(dist, src, dst, source, num_segments: int, max_weight: int):
-    """Exit-time canonicalization: per reached non-source vertex, parent =
-    MIN u over in-edges with ``dist[u] + w(u, v) == dist[v]`` — the same
-    combine, one pass, identical on every arm.  Every optimal predecessor
-    qualifies (its dist is final), so this is the global canonical
-    tie-break, not a schedule artifact."""
-    w = edge_weights(src, dst, max_weight)
+def _sssp_parents(dist, src, dst, w, ends, source, num_segments: int):
+    """The exit-time parent pass, one rule for every arm and dtype.  A
+    reached vertex takes the least ``u`` among its tight in-edges
+    (``dist[u] + w == dist[v]``, in the weights' own arithmetic) with
+    ``dist[u] < dist[v]``.  A vertex whose tight in-edges all tie takes,
+    in rounds, the least tied ``u`` whose parent is already fixed: a
+    tie round runs only where a zero-effect edge exists, never under
+    integer weights >= 1.  Every reached vertex has a tight in-edge (its
+    distance is some in-neighbour's plus a weight), and the tight edges
+    that tie lead back to a fixed vertex, so every reached vertex gets a
+    parent and the parents form a tree."""
+    far = unreached(dist.dtype)
     ds = dist[src]
-    ok = (ds != INT32_MAX) & (ds + w == dist[dst])
+    dd = dist[dst]
+    tight = (ds != far) & (ds + w == dd)
     parent = combine_min(
-        jnp.where(ok, src, INT32_MAX), dst, num_segments
+        jnp.where(tight & (ds < dd), src, INT32_MAX), dst, num_segments, ends
     )
-    reached = dist != INT32_MAX
-    parent = jnp.where(
+    parent = parent.at[source].set(jnp.asarray(source, jnp.int32))
+    reached = dist != far
+    tied = tight & (ds == dd)
+
+    def open_(p):
+        return (reached & (p == INT32_MAX)).any()
+
+    def fix_round(carry):
+        p, _ = carry
+        fixed = p != INT32_MAX
+        via = combine_min(
+            jnp.where(tied & fixed[src], src, INT32_MAX), dst, num_segments,
+            ends,
+        )
+        grew = (~fixed & (via != INT32_MAX)).any()
+        return jnp.where(fixed, p, via), grew
+
+    parent, _ = jax.lax.while_loop(
+        lambda c: c[1], fix_round, (parent, open_(parent))
+    )
+    return jnp.where(
         reached & (parent != INT32_MAX), parent, jnp.int32(NO_PARENT)
     )
-    return parent.at[source].set(jnp.asarray(source, jnp.int32))
+
+
+@functools.partial(jax.jit, static_argnames=("max_weight",))
+@traced("algo.sssp_hash_weights")
+def _hash_weights(src, dst, max_weight: int):
+    """The weight operand of a graph without stored weights."""
+    return edge_weights(src, dst, max_weight)
+
+
+@functools.partial(jax.jit, static_argnames=("num_segments",))
+@traced("algo.sssp_dst_ends")
+def _dst_ends(dst, num_segments: int):
+    """Each destination's last edge, once per search for every program
+    of it (:func:`bfs_tpu.ops.relax.dst_ends`)."""
+    return dst_ends(dst, num_segments)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=(
-        "num_vertices", "max_weight", "delta", "max_rounds", "packed",
-    ),
+    static_argnames=("num_vertices", "delta", "max_rounds", "packed"),
 )
 @traced("algo.sssp_fused")
 def _sssp_fused(
     src,
     dst,
+    w,
+    ends,
     source,
     num_vertices: int,
-    max_weight: int,
-    delta: int,
+    delta,
     max_rounds: int,
     packed: bool = False,
 ):
-    """The fused SSSP program: weights from the endpoint hash, then one
-    ``while_loop`` of min-plus supersteps (packed or unpacked carry)."""
-    w = edge_weights(src, dst, max_weight)
+    """The fused SSSP program: one ``while_loop`` of min-plus supersteps
+    over the weight operand (packed or unpacked carry); ``ends``
+    (:func:`bfs_tpu.ops.relax.dst_ends`) gives the combine its scan form."""
     if packed:
         pstate = init_packed_sssp_state(num_vertices, source, delta)
 
@@ -271,23 +369,23 @@ def _sssp_fused(
             return s.changed & (s.rounds < max_rounds)
 
         def pbody(s):
-            return sssp_superstep_packed(s, src, dst, w, delta)
+            return sssp_superstep_packed(s, src, dst, w, delta, ends=ends)
 
         return jax.lax.while_loop(pcond, pbody, pstate)
-    state = init_sssp_state(num_vertices, source, delta)
+    state = init_sssp_state(num_vertices, source, delta, w.dtype)
 
     def cond(s):
         return s.changed & (s.rounds < max_rounds)
 
     def body(s):
-        return sssp_superstep(s, src, dst, w, delta)
+        return sssp_superstep(s, src, dst, w, delta, ends=ends)
 
     return jax.lax.while_loop(cond, body, state)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("num_vertices", "max_weight", "delta", "packed"),
+    static_argnames=("num_vertices", "delta", "packed"),
     donate_argnums=(0,),
 )
 @traced("algo.sssp_segment")
@@ -296,16 +394,16 @@ def _sssp_segment(
     seg_end,
     src,
     dst,
+    w,
+    ends,
     num_vertices: int,
-    max_weight: int,
-    delta: int,
+    delta,
     packed: bool = False,
 ):
     """ONE bounded segment of the fused loop — the checkpointable twin.
     ``seg_end`` is a TRACED round bound: advancing it costs no retrace,
     and a sequence of segments runs exactly the supersteps the fused
     program would (bit-identical carries at every boundary)."""
-    w = edge_weights(src, dst, max_weight)
 
     def cond(s):
         return s.changed & (s.rounds < seg_end)
@@ -313,12 +411,12 @@ def _sssp_segment(
     if packed:
 
         def pbody(s):
-            return sssp_superstep_packed(s, src, dst, w, delta)
+            return sssp_superstep_packed(s, src, dst, w, delta, ends=ends)
 
         return jax.lax.while_loop(cond, pbody, state)
 
     def body(s):
-        return sssp_superstep(s, src, dst, w, delta)
+        return sssp_superstep(s, src, dst, w, delta, ends=ends)
 
     return jax.lax.while_loop(cond, body, state)
 
@@ -327,48 +425,72 @@ def _sssp_segment(
 
 @dataclass
 class SsspResult:
-    """Host-side result in the oracle's shapes: int32[V] ``dist``
-    (INT32_MAX = unreached) and canonical int32[V] ``parent`` (sentinel
-    slot stripped).  ``rounds`` counts executed supersteps including
-    bucket-advance rounds; ``packed`` reports the carry flavor that
-    PRODUCED the result (False after a truncation fallback)."""
+    """Host-side result in the oracle's shapes: ``dist`` (int32 with
+    INT32_MAX, or float32 with +inf, for unreached) and int32[V]
+    ``parent`` (sentinel slot stripped).  ``rounds`` counts executed
+    supersteps including bucket-advance rounds; ``max_weight`` is the
+    hash's range, None for stored weights; ``packed`` reports the carry
+    flavor that PRODUCED the result (False after a truncation
+    fallback)."""
 
     dist: np.ndarray
     parent: np.ndarray
     rounds: int
-    max_weight: int
-    delta: int
+    max_weight: int | None
+    delta: int | float
     packed: bool
     truncated_fallbacks: int = 0
 
-    def dist_to(self, v: int) -> int:
-        return int(self.dist[v])
+    def dist_to(self, v: int):
+        return self.dist[v].item()
 
     def has_path_to(self, v: int) -> bool:
-        return int(self.dist[v]) != int(INT32_MAX)
+        return bool(self.dist[v] != unreached(self.dist.dtype))
 
 
-def _rounds_cap(num_vertices: int, max_weight: int, max_rounds) -> int:
-    """Safety bound on supersteps: within a bucket each round extends the
-    settled distance prefix by >= 1 weight unit (integer weights >= 1),
-    and each advance covers >= 1 dirty vertex — so total rounds are
-    bounded by max finite distance + bucket count, <= (w_max + 1) * V.
-    The loop exits on convergence long before this on any real graph."""
+def _rounds_cap(num_vertices: int, max_rounds) -> int:
+    """Supersteps a search may run.  An explicit ``max_rounds`` stops the
+    loop there with whatever it holds.  The default is a true bound for
+    weights >= 0, float ones included (float32 addition is monotone and
+    never makes a distance smaller): a bucket drains within as many
+    supersteps as it has vertices to settle, the next superstep advances
+    it, and each bucket settles at least one vertex, so a search takes
+    at most 2V supersteps.  A search still changing there raises
+    (:func:`_converged`)."""
     if max_rounds is not None:
         return int(max_rounds)
-    return (int(max_weight) + 1) * (int(num_vertices) + 1)
+    return 2 * (int(num_vertices) + 1)
 
 
-def _finish(dist_dev, src_dev, dst_dev, source, n, max_weight):
-    dist = np.asarray(jax.device_get(dist_dev))
-    parent = np.asarray(
-        jax.device_get(
-            _sssp_parents(
-                dist_dev, src_dev, dst_dev, jnp.int32(source), n, max_weight
-            )
+def _converged(changed, rounds: int, max_rounds, source: int) -> None:
+    """Raise where a search stopped at the default cap with work left:
+    its distances would be partial."""
+    if max_rounds is None and bool(changed):
+        raise RuntimeError(
+            f"SSSP from {source} still changing after {rounds} supersteps, "
+            "the bound for weights >= 0"
         )
-    )
-    return dist, parent
+
+
+def _parents_and_fetch(dist_dev, rounds, changed, src_dev, dst_dev, w_dev,
+                       ends, source: int, n: int):
+    """Dispatch the parent pass behind the search, then bring ``(dist,
+    parent, rounds, changed)`` to the host, each boundary under its span.
+    The host copy is queued at dispatch, so it starts when the programs
+    end."""
+    with obs_span("sssp.parents"):
+        parent_dev = _sssp_parents(
+            dist_dev, src_dev, dst_dev, w_dev, ends, jnp.int32(source), n
+        )
+    out = (dist_dev, parent_dev, rounds, changed)
+    for leaf in out:
+        leaf.copy_to_host_async()
+    with obs_span("sssp.device_wait"):
+        out = jax.block_until_ready(out)
+    nbytes = sum(int(x.nbytes) for x in out)
+    with obs_span("sssp.fetch", bytes=nbytes):
+        dist, parent, rounds, changed = jax.device_get(out)
+    return np.asarray(dist), np.asarray(parent), int(rounds), bool(changed)
 
 
 def resolve_packed16(num_vertices: int) -> bool:
@@ -379,26 +501,41 @@ def resolve_packed16(num_vertices: int) -> bool:
     return resolve_packed(packed16_fits(num_vertices))
 
 
+def _weights(weight, src_dev, dst_dev, max_weight: int):
+    """The weight operand and the ``max_weight`` a result reports: the
+    stored weights as given, else the endpoint hash."""
+    if weight is not None:
+        return weight, None
+    return _hash_weights(src_dev, dst_dev, max_weight=max_weight), max_weight
+
+
+def _device_operands(graph: Graph, block: int):
+    dg = build_device_graph(graph, block=block)
+    w = None if dg.weight is None else jnp.asarray(dg.weight)
+    return dg, jnp.asarray(dg.src), jnp.asarray(dg.dst), w
+
+
 def sssp(
     graph: Graph,
     source: int = 0,
     *,
     max_weight: int = DEFAULT_MAX_WEIGHT,
-    delta: int | str | None = None,
+    delta: int | float | str | None = None,
     max_rounds: int | None = None,
     packed: bool | None = None,
     block: int = 1024,
 ) -> SsspResult:
     """Single-source shortest paths on the fused push engine.
 
-    Weights are ``edge_weights(src, dst, max_weight)`` — pass the same
-    ``max_weight`` to :func:`bfs_tpu.oracle.sssp.dijkstra` (with
+    The graph's stored weights (``graph.weight``) when it has them, else
+    ``edge_weights(src, dst, max_weight)`` — pass the same ``max_weight``
+    to :func:`bfs_tpu.oracle.sssp.dijkstra` (with
     :func:`bfs_tpu.algo.substrate.edge_weights_np`) for oracle parity.
     ``packed=None`` resolves the dist:16|parent:16 arm automatically and
     falls back unpacked when a final distance hits the 16-bit clamp."""
-    dg = build_device_graph(graph, block=block)
+    dg, src, dst, w = _device_operands(graph, block)
     return sssp_device(
-        jnp.asarray(dg.src), jnp.asarray(dg.dst), dg.num_vertices, source,
+        src, dst, dg.num_vertices, source, weight=w,
         max_weight=max_weight, delta=delta, max_rounds=max_rounds,
         packed=packed,
     )
@@ -410,57 +547,72 @@ def sssp_device(
     num_vertices: int,
     source: int = 0,
     *,
+    weight=None,
     max_weight: int = DEFAULT_MAX_WEIGHT,
-    delta: int | str | None = None,
+    delta: int | float | str | None = None,
     max_rounds: int | None = None,
     packed: bool | None = None,
 ) -> SsspResult:
     """:func:`sssp` against ALREADY-RESIDENT sentinel-padded device edge
     arrays — the form the serve registry's residency layer feeds
-    (:func:`bfs_tpu.serve.algo.registry_sssp`): operands upload once per
-    (graph, engine) epoch and every traversal reuses them."""
+    (:func:`bfs_tpu.serve.algo.registry_sssp`) and the one a caller that
+    searches one graph many times keeps: operands upload once and every
+    search reuses them.  ``weight`` is the device weight array aligned
+    with them (``build_device_graph(...).weight``); None fills it with
+    the endpoint hash."""
     v = int(num_vertices)
     n = v + 1
-    delta_i = resolve_delta(delta)
-    cap = _rounds_cap(v, max_weight, max_rounds)
-    use_packed = (
+    w, max_w = _weights(weight, src_dev, dst_dev, max_weight)
+    floating = is_float(w.dtype)
+    delta_v = resolve_delta(delta, floating=floating)
+    cap = _rounds_cap(v, max_rounds)
+    if packed and floating:
+        raise ValueError("the packed16 carry takes integer weights only")
+    use_packed = not floating and (
         resolve_packed16(v) if packed is None else bool(packed)
     )
-    fallbacks = 0
     if use_packed and not packed16_fits(v):
         raise ValueError(
             f"packed16 carry needs V < {PACKED16_MAX_V}, got {v}"
         )
-    if use_packed:
-        pstate = _sssp_fused(
-            src_dev, dst_dev, jnp.int32(source),
-            num_vertices=v, max_weight=max_weight, delta=delta_i,
-            max_rounds=cap, packed=True,
-        )
-        if not bool(jax.device_get(packed16_truncated(pstate.packed))):
-            dist_dev = packed16_dist(pstate.packed)
-            dist, parent = _finish(
-                dist_dev, src_dev, dst_dev, source, n, max_weight
+    fallbacks = 0
+    truncated = False
+    with obs_span("sssp.search"):
+        ends = _dst_ends(dst_dev, num_segments=n)
+        if use_packed:
+            with obs_span("sssp.launch"):
+                pstate = _sssp_fused(
+                    src_dev, dst_dev, w, ends, jnp.int32(source),
+                    num_vertices=v, delta=delta_v, max_rounds=cap,
+                    packed=True,
+                )
+            truncated = bool(jax.device_get(packed16_truncated(pstate.packed)))
+            if truncated:
+                fallbacks = 1  # clamp hit: the packed dists are not trustworthy
+            else:
+                dist, parent, rounds, changed = _parents_and_fetch(
+                    packed16_dist(pstate.packed), pstate.rounds,
+                    pstate.changed, src_dev, dst_dev, w, ends, source, n,
+                )
+        if not use_packed or truncated:
+            with obs_span("sssp.launch"):
+                state = _sssp_fused(
+                    src_dev, dst_dev, w, ends, jnp.int32(source),
+                    num_vertices=v, delta=delta_v, max_rounds=cap,
+                    packed=False,
+                )
+            dist, parent, rounds, changed = _parents_and_fetch(
+                state.dist, state.rounds, state.changed,
+                src_dev, dst_dev, w, ends, source, n,
             )
-            return SsspResult(
-                dist=dist[:v], parent=parent[:v],
-                rounds=int(jax.device_get(pstate.rounds)),
-                max_weight=max_weight, delta=delta_i, packed=True,
-            )
-        fallbacks = 1  # clamp hit: the packed dists are not trustworthy
-    state = _sssp_fused(
-        src_dev, dst_dev, jnp.int32(source),
-        num_vertices=v, max_weight=max_weight, delta=delta_i,
-        max_rounds=cap, packed=False,
-    )
-    dist, parent = _finish(
-        state.dist, src_dev, dst_dev, source, n, max_weight
-    )
+    registry = get_registry()
+    registry.counter("sssp_searches")
+    registry.counter("sssp_supersteps", rounds)
+    _converged(changed, rounds, max_rounds, source)
     return SsspResult(
-        dist=dist[:v], parent=parent[:v],
-        rounds=int(jax.device_get(state.rounds)),
-        max_weight=max_weight, delta=delta_i, packed=False,
-        truncated_fallbacks=fallbacks,
+        dist=dist[:v], parent=parent[:v], rounds=rounds,
+        max_weight=max_w, delta=delta_v,
+        packed=use_packed and not truncated, truncated_fallbacks=fallbacks,
     )
 
 
@@ -480,7 +632,7 @@ def sssp_segmented(
     *,
     ckpt,
     max_weight: int = DEFAULT_MAX_WEIGHT,
-    delta: int | str | None = None,
+    delta: int | float | str | None = None,
     max_rounds: int | None = None,
     packed: bool | None = None,
     block: int = 1024,
@@ -491,12 +643,14 @@ def sssp_segmented(
     results for any segmentation, kill/resume included."""
     from .substrate import drive_segments
 
-    dg = build_device_graph(graph, block=block)
+    dg, src_dev, dst_dev, w = _device_operands(graph, block)
     v = dg.num_vertices
     n = v + 1
-    delta_i = resolve_delta(delta)
-    cap = _rounds_cap(v, max_weight, max_rounds)
-    src_dev, dst_dev = jnp.asarray(dg.src), jnp.asarray(dg.dst)
+    w, max_w = _weights(w, src_dev, dst_dev, max_weight)
+    floating = is_float(w.dtype)
+    delta_v = resolve_delta(delta, floating=floating)
+    cap = _rounds_cap(v, max_rounds)
+    ends = _dst_ends(dst_dev, num_segments=n)
 
     def run_flavor(use_packed: bool):
         cls = PackedSsspState if use_packed else SsspState
@@ -507,14 +661,13 @@ def sssp_segmented(
                     k: jnp.asarray(arrays[k]) for k in cls._fields
                 })
             if use_packed:
-                return init_packed_sssp_state(v, source, delta_i)
-            return init_sssp_state(v, source, delta_i)
+                return init_packed_sssp_state(v, source, delta_v)
+            return init_sssp_state(v, source, delta_v, w.dtype)
 
         def seg(carry, seg_end):
             return _sssp_segment(
-                carry, seg_end, src_dev, dst_dev,
-                num_vertices=v, max_weight=max_weight, delta=delta_i,
-                packed=use_packed,
+                carry, seg_end, src_dev, dst_dev, w, ends,
+                num_vertices=v, delta=delta_v, packed=use_packed,
             )
 
         return drive_segments(
@@ -522,29 +675,34 @@ def sssp_segmented(
             packed=use_packed, cap=cap,
         )
 
-    use_packed = resolve_packed16(v) if packed is None else bool(packed)
+    use_packed = not floating and (
+        resolve_packed16(v) if packed is None else bool(packed)
+    )
     fallbacks = 0
     if use_packed:
-        pstate, rounds, _ = run_flavor(True)
+        pstate, rounds, changed = run_flavor(True)
         if not bool(jax.device_get(packed16_truncated(pstate.packed))):
-            dist, parent = _finish(
-                packed16_dist(pstate.packed), src_dev, dst_dev, source,
-                n, max_weight,
+            dist, parent, _, _ = _parents_and_fetch(
+                packed16_dist(pstate.packed), pstate.rounds, pstate.changed,
+                src_dev, dst_dev, w, ends, source, n,
             )
             ckpt.clear()
+            _converged(changed, rounds, max_rounds, source)
             return SsspResult(
                 dist=dist[:v], parent=parent[:v], rounds=rounds,
-                max_weight=max_weight, delta=delta_i, packed=True,
+                max_weight=max_w, delta=delta_v, packed=True,
             )
         fallbacks = 1
         ckpt.clear()  # packed epochs cannot feed the unpacked re-run
-    state, rounds, _ = run_flavor(False)
-    dist, parent = _finish(
-        state.dist, src_dev, dst_dev, source, n, max_weight
+    state, rounds, changed = run_flavor(False)
+    dist, parent, _, _ = _parents_and_fetch(
+        state.dist, state.rounds, state.changed,
+        src_dev, dst_dev, w, ends, source, n,
     )
     ckpt.clear()
+    _converged(changed, rounds, max_rounds, source)
     return SsspResult(
         dist=dist[:v], parent=parent[:v], rounds=rounds,
-        max_weight=max_weight, delta=delta_i, packed=False,
+        max_weight=max_w, delta=delta_v, packed=False,
         truncated_fallbacks=fallbacks,
     )

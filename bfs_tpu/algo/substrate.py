@@ -22,13 +22,15 @@ sharded program families.
 This module also owns the two pieces the algorithms share:
 
   * :func:`edge_weights_np` / ``edge_weights`` — deterministic per-edge
-    weights as a HASH of the endpoints, not a parallel array that must be
-    permuted alongside every relayout.  ``w(u, v) = f(u, v)`` survives
-    dst-sorting, sentinel padding and round-robin sharding with zero
-    plumbing: any engine recomputes its shard's weights from the edge
-    arrays it already holds (the sharded programs do it inside the
-    ``shard_map`` body), and the host oracle recomputes the identical
-    values from the host edge list.
+    weights as a HASH of the endpoints, for graphs without stored
+    weights.  ``w(u, v) = f(u, v)`` survives dst-sorting, sentinel
+    padding and round-robin sharding with zero plumbing: any engine
+    recomputes its shard's weights from the edge arrays it already holds
+    (the sharded programs do it inside the ``shard_map`` body), and the
+    host oracle recomputes the identical values from the host edge list.
+    A graph's stored weights (``Graph.weight``, one per input edge, as
+    Graph500 kernel 3 draws them) travel instead as an array that
+    ``build_device_graph`` permutes and pads with the edges.
   * :func:`drive_segments` — the generic segmented-traversal driver over
     :class:`~bfs_tpu.resilience.superstep_ckpt.SuperstepCheckpointer`:
     bounded device segments, a durable epoch per boundary, the
@@ -38,6 +40,7 @@ This module also owns the two pieces the algorithms share:
 
 from __future__ import annotations
 
+import math
 import os
 import time
 from dataclasses import dataclass
@@ -83,13 +86,16 @@ SEMIRINGS = {
         packable=True,  # level:6|parent:26 (ops/packed.py)
         mxu_eligible=True,  # boolean masks: AND/popcount tiles (PR 15)
     ),
+    # The state is typed by the weights: int32 dist (identity INT32_MAX)
+    # under integer weights, hashed or stored; float32 dist (identity
+    # +inf) under stored float weights (Graph500 kernel 3).
     "sssp": Semiring(
         name="sssp",
-        contribute="dist[src] + w(src, dst) if frontier[src]",
+        contribute="dist[src] + w if frontier[src]; w hashed or stored",
         combine="segment_min over dst",
         identity=int(INT32_MAX),
         state=("dist", "dirty", "threshold"),
-        packable=True,  # dist:16|parent:16 (algo/sssp.py, V < 2^16-1)
+        packable=True,  # dist:16|parent:16, integer weights, V < 2^16-1
         mxu_eligible=False,  # valued contributions: no popcount encoding
     ),
     "cc": Semiring(
@@ -153,22 +159,32 @@ def edge_weights(src, dst, max_weight: int):
 
 # ------------------------------------------------------------ delta knob --
 
-def resolve_delta(delta: int | str | None = None) -> int:
-    """The delta-stepping bucket width: explicit argument, else
-    ``BFS_TPU_SSSP_DELTA`` (int, or ``inf`` for one bucket = plain
-    frontier Bellman-Ford), else 64 — about half the default mean weight,
-    the classic delta ~ w_mean starting point.  Returned as the int32
-    threshold increment (``inf`` maps to INT32_MAX: the first bucket
-    already spans every finite distance)."""
+def resolve_delta(delta: int | float | str | None = None, *,
+                  floating: bool = False) -> int | float:
+    """The delta-stepping bucket width, in the weights' type: explicit
+    argument, else ``BFS_TPU_SSSP_DELTA`` (a number, or ``inf`` for one
+    bucket = plain frontier Bellman-Ford), else the default by the
+    weights.  Integer weights default to 64, about half the default mean
+    hash weight, the classic delta ~ w_mean starting point; float weights
+    default to one bucket, since every superstep sweeps every edge and one
+    bucket takes the fewest supersteps (PERF.md, Graph500 kernel 3).
+
+    Returned as the threshold increment: for integer weights an int >= 1
+    (one bucket maps to INT32_MAX: the first bucket already spans every
+    finite distance, a fractional width rounds up), for float weights a
+    float > 0 (one bucket is ``inf``)."""
     if delta is None:
-        delta = knobs.get("BFS_TPU_SSSP_DELTA")
+        unset = knobs.raw("BFS_TPU_SSSP_DELTA") in (None, "")
+        delta = (math.inf if floating and unset
+                 else knobs.get("BFS_TPU_SSSP_DELTA"))
     if isinstance(delta, str):
-        if delta.lower() in ("inf", "infinite", "single"):
-            return int(INT32_MAX)
-        delta = int(delta)
-    if delta <= 0:
-        return int(INT32_MAX)
-    return min(int(delta), int(INT32_MAX))
+        delta = knobs.parse_value("BFS_TPU_SSSP_DELTA", delta)
+    delta = float(delta)
+    if not delta > 0:
+        delta = math.inf
+    if floating:
+        return delta
+    return int(INT32_MAX) if delta >= INT32_MAX else math.ceil(delta)
 
 
 # ------------------------------------------------------ segmented driver --

@@ -1048,68 +1048,80 @@ def _spec_sharded_relay(flavor: str = "dense"):
     )
 
 
-def _spec_algo_sssp_fused(packed: bool):
-    """The semiring SSSP programs (ISSUE 16): min-plus supersteps over
-    hash-recomputed weights, unpacked int32 or packed dist:16|parent:16
-    carry — same HBM/donation rules as the BFS fused programs."""
+def _spec_algo_sssp_fused(packed: bool, floating: bool = False):
+    """The semiring SSSP programs (ISSUE 16): min-plus supersteps over the
+    weight operand — the endpoint hash under an unpacked int32 or packed
+    dist:16|parent:16 carry, or stored float32 weights under a float32
+    carry (Graph500 kernel 3) — same HBM/donation rules as the BFS fused
+    programs."""
     import jax.numpy as jnp
 
     from ..algo.sssp import _sssp_fused
-    from ..graph.csr import build_device_graph
 
-    dg = _memo("dg", lambda: build_device_graph(_tiny_graph()))
+    dg, src, dst, w, ends = _memo("sssp_operands", _tiny_sssp_operands)
     v = dg.num_vertices
+    if floating:
+        w = w.astype(jnp.float32) / 32
+    flavor = "_packed" if packed else "_float" if floating else ""
     return Program(
-        name=f"algo.sssp_fused{'_packed' if packed else ''}",
+        name=f"algo.sssp_fused{flavor}",
         path="bfs_tpu/algo/sssp.py",
         fn=_sssp_fused,
-        args=(jnp.asarray(dg.src), jnp.asarray(dg.dst), jnp.int32(0)),
+        args=(src, dst, w, ends, jnp.int32(0)),
         static_kwargs=dict(
-            num_vertices=v, max_weight=31, delta=64, max_rounds=64,
-            packed=packed,
+            num_vertices=v, delta=float("inf") if floating else 64,
+            max_rounds=64, packed=packed,
         ),
         v_elements=v, packed=packed, budget_bytes=_hbm_envelope(),
     )
+
+
+def _tiny_sssp_operands():
+    """The tiny graph's device edges with hash weights in ``[1, 31]``,
+    and each destination's last edge (the scan form of the combine)."""
+    import jax.numpy as jnp
+
+    from ..algo.substrate import edge_weights
+    from ..graph.csr import build_device_graph
+    from ..ops.relax import dst_ends
+
+    dg = _memo("dg", lambda: build_device_graph(_tiny_graph()))
+    src, dst = jnp.asarray(dg.src), jnp.asarray(dg.dst)
+    ends = dst_ends(dst, dg.num_vertices + 1)
+    return dg, src, dst, edge_weights(src, dst, 31), ends
 
 
 def _spec_algo_sssp_segment():
     import jax.numpy as jnp
 
     from ..algo.sssp import _sssp_segment, init_sssp_state
-    from ..graph.csr import build_device_graph
 
-    dg = _memo("dg", lambda: build_device_graph(_tiny_graph()))
+    dg, src, dst, w, ends = _memo("sssp_operands", _tiny_sssp_operands)
     v = dg.num_vertices
     return Program(
         name="algo.sssp_segment", path="bfs_tpu/algo/sssp.py",
         fn=_sssp_segment,
-        args=(
-            init_sssp_state(v, 0, 64), jnp.int32(8),
-            jnp.asarray(dg.src), jnp.asarray(dg.dst),
-        ),
-        static_kwargs=dict(
-            num_vertices=v, max_weight=31, delta=64, packed=False
-        ),
+        args=(init_sssp_state(v, 0, 64), jnp.int32(8), src, dst, w, ends),
+        static_kwargs=dict(num_vertices=v, delta=64, packed=False),
         v_elements=v, donate={0: "state"}, budget_bytes=_hbm_envelope(),
     )
 
 
 def _spec_algo_sssp_parents():
-    """The exit-time parent canonicalization pass — the one program every
-    SSSP arm shares, which is WHY parents are schedule-independent."""
+    """The exit-time parent pass — the one program every SSSP arm
+    shares, which is WHY parents are schedule-independent."""
     import jax.numpy as jnp
 
     from ..algo.sssp import _sssp_parents
-    from ..graph.csr import build_device_graph
 
-    dg = _memo("dg", lambda: build_device_graph(_tiny_graph()))
+    dg, src, dst, w, ends = _memo("sssp_operands", _tiny_sssp_operands)
     v = dg.num_vertices
     dist = jnp.zeros((v + 1,), jnp.int32)
     return Program(
         name="algo.sssp_parents", path="bfs_tpu/algo/sssp.py",
         fn=_sssp_parents,
-        args=(dist, jnp.asarray(dg.src), jnp.asarray(dg.dst), jnp.int32(0)),
-        static_kwargs=dict(num_segments=v + 1, max_weight=31),
+        args=(dist, src, dst, w, ends, jnp.int32(0)),
+        static_kwargs=dict(num_segments=v + 1),
         v_elements=v, budget_bytes=_hbm_envelope(),
     )
 
@@ -1295,6 +1307,7 @@ PROGRAM_SPECS = {
     "grid.segment": _spec_grid_segment,
     "algo.sssp_fused": lambda: _spec_algo_sssp_fused(False),
     "algo.sssp_fused_packed": lambda: _spec_algo_sssp_fused(True),
+    "algo.sssp_fused_float": lambda: _spec_algo_sssp_fused(False, True),
     "algo.sssp_segment": _spec_algo_sssp_segment,
     "algo.sssp_parents": _spec_algo_sssp_parents,
     "algo.cc_fused_push": lambda: _spec_algo_cc_fused("push"),

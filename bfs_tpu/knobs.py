@@ -44,6 +44,7 @@ never pull jax (or anything heavy) into an importer.
 
 from __future__ import annotations
 
+import math
 import os
 import re
 from dataclasses import dataclass, field
@@ -114,14 +115,15 @@ def _parse_tristate(raw: str):
 
 
 def _parse_delta(raw: str):
-    """Delta-stepping bucket width: int (non-positive means one bucket),
-    or inf/infinite/single for plain frontier Bellman-Ford."""
+    """Delta-stepping bucket width: a positive number (an int, or a float
+    for float weights), or inf/infinite/single, like any non-positive
+    number, for one bucket (plain frontier Bellman-Ford): ``math.inf``."""
     if raw.lower() in ("inf", "infinite", "single"):
-        return _INT32_MAX
-    v = int(raw)
-    if v <= 0:
-        return _INT32_MAX
-    return min(v, _INT32_MAX)
+        return math.inf
+    v = float(raw)
+    if not v > 0:
+        return math.inf
+    return int(v) if v.is_integer() else v
 
 
 def _parse_mesh(raw: str):
@@ -336,8 +338,9 @@ KNOBS: dict[str, Knob] = {k.name: k for k in (
        "entries are dropped and re-fetched",
        affects=_FLAVOR, canary="yes"),
     _k("BFS_TPU_SSSP_DELTA", "spec", "64", _parse_delta,
-       "delta-stepping bucket width (int, or inf/single for plain "
-       "frontier Bellman-Ford); non-positive = one bucket",
+       "delta-stepping bucket width (a float or int, or inf/single for "
+       "plain frontier Bellman-Ford); non-positive = one bucket; unset, "
+       "64 under integer weights and inf under float weights",
        affects=(*_FLAVOR, "journal", "serve"), canary="wide",
        journal_key="sssp_delta"),
     _k("BFS_TPU_CKPT", "spec", "off", _parse_ckpt,

@@ -112,8 +112,48 @@ def relax_superstep(
     return apply_candidates(state, cand_parent)
 
 
+#: Lanes of a row in the scan form of :func:`combine_min`.
+SCAN_ROW = 128
+
+
+def dst_ends(dst, num_segments: int) -> jax.Array:
+    """The index of the last edge into each destination of a dst-sorted
+    edge array, or ``len(dst)`` (out of range) for a destination with no
+    in-edge: the operand of :func:`combine_min`'s scan form, fixed per
+    graph, so a loop computes it once (a binary search per destination)."""
+    ids = jnp.arange(num_segments, dtype=jnp.int32)
+    last = jnp.searchsorted(dst, ids, side="right").astype(jnp.int32) - 1
+    has = (last >= 0) & (dst[jnp.maximum(last, 0)] == ids)
+    return jnp.where(has, last, jnp.int32(dst.shape[0]))
+
+
+def _identity(dtype):
+    """The min identity of ``dtype``: +inf, or the largest integer."""
+    if jnp.issubdtype(dtype, jnp.floating):
+        return np.array(np.inf, dtype)
+    return np.array(np.iinfo(dtype).max, dtype)
+
+
+def _shift(x, k: int, fill):
+    """``x`` moved ``k`` places up its last axis, ``fill`` coming in."""
+    pad = [(0, 0)] * (x.ndim - 1) + [(k, 0)]
+    return jnp.pad(x[..., :-k], pad, constant_values=fill)
+
+
+def _segmented_min_scan(x, d, fill):
+    """Inclusive min-scan of ``x`` along its last axis, restarting where
+    the sorted keys ``d`` change (Hillis-Steele doubling: equal keys ``k``
+    apart bound a run of equal keys)."""
+    k = 1
+    while k < x.shape[-1]:
+        same = _shift(d, k, -1) == d
+        x = jnp.where(same, jnp.minimum(x, _shift(x, k, fill)), x)
+        k *= 2
+    return x
+
+
 # bfs_tpu: hot traced
-def combine_min(values, dst, num_segments: int) -> jax.Array:
+def combine_min(values, dst, num_segments: int, ends=None) -> jax.Array:
     """THE semiring combine: one segmented min of per-edge contribution
     values over edge destinations (identity = the dtype's max sentinel).
 
@@ -122,10 +162,29 @@ def combine_min(values, dst, num_segments: int) -> jax.Array:
     ``dist[src] + w`` min-plus sums, connected components contributes
     ``label[src]`` (bfs_tpu/algo/substrate.py's semiring table).  Edges
     must be dst-sorted with sentinel padding (csr.build_device_graph) so
-    ``indices_are_sorted=True`` holds and padded lanes are inert."""
-    return jax.ops.segment_min(
-        values, dst, num_segments=num_segments, indices_are_sorted=True
-    )
+    ``indices_are_sorted=True`` holds and padded lanes are inert.
+
+    With ``ends`` (:func:`dst_ends` of the same ``dst``) the same minima
+    come from a scan in place of the scatter: a segmented min-scan along
+    rows of up to :data:`SCAN_ROW` edges, the rows' tails scanned by
+    their keys and carried into the next row's first run, then one
+    gather of each destination's last edge.  On the TPU the scatter
+    serialises over every edge; the scan is dense vector work plus one
+    gather per destination."""
+    if ends is None:
+        return jax.ops.segment_min(
+            values, dst, num_segments=num_segments, indices_are_sorted=True
+        )
+    fill = _identity(values.dtype)
+    e = values.shape[0]
+    width = min(SCAN_ROW, e & -e)
+    d = dst.reshape(-1, width)
+    x = _segmented_min_scan(values.reshape(-1, width), d, fill)
+    tails = _segmented_min_scan(x[:, -1], d[:, -1], fill)
+    carry = jnp.where(_shift(d[:, -1], 1, -1) == d[:, 0],
+                      _shift(tails, 1, fill), fill)
+    x = jnp.where(d == d[:, :1], jnp.minimum(x, carry[:, None]), x)
+    return x.reshape(-1).at[ends].get(mode="fill", fill_value=fill)
 
 
 def _push_candidates(frontier, src, dst, num_segments: int) -> jax.Array:

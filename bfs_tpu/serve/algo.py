@@ -11,7 +11,8 @@ registry's HBM budget governs the algorithms exactly as it governs BFS.
 
 Weights need no residency of their own: the SSSP arm recomputes them
 on device from the resident edge endpoints
-(:func:`bfs_tpu.algo.substrate.edge_weights`).
+(:func:`bfs_tpu.algo.substrate.edge_weights`), and refuses a graph that
+stores weights of its own.
 """
 
 from __future__ import annotations
@@ -40,6 +41,10 @@ def registry_sssp(
     (max_weight / delta / max_rounds / packed)."""
     rec = registry.pin(name)
     try:
+        if rec.graph is not None and rec.graph.weight is not None:
+            raise ValueError(
+                f"graph {name!r} stores its weights; registry SSSP runs on "
+                "the endpoint hash (bfs_tpu.algo.sssp.sssp takes them)")
         src_dev, dst_dev = registry.acquire_for(rec, "push")
         return sssp_device(
             src_dev, dst_dev, _num_vertices(registry, rec, "push"),

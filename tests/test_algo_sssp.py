@@ -1,7 +1,9 @@
 """Weighted SSSP on the semiring substrate (ISSUE 16).
 
 Covers: min-plus supersteps vs the host Dijkstra oracle (dist AND
-canonical parents, bit-for-bit) on star/path/gnm/rmat; delta-stepping
+canonical parents, bit-for-bit) on star/path/gnm/rmat; stored float32
+weights (Graph500 kernel 3) against the float32 oracle, bit for bit, with
+parents that form a tree where zero weights tie; delta-stepping
 bucket invariance (delta in {1, 17, inf, default} -> one fixpoint); the
 packed16 (dist:16|parent:16) arm's schedule identity with the unpacked
 carry; the truncation canary -> unpacked fallback; fused-vs-segmented
@@ -10,6 +12,7 @@ edge-sharded parity; the on-device invariant counters; and the semiring
 registry / hash-weight / delta-knob contracts.
 """
 
+import importlib
 import os
 
 import jax.numpy as jnp
@@ -27,7 +30,7 @@ from bfs_tpu.algo import (
 )
 from bfs_tpu.algo.sssp import PACKED16_MAX_V, packed16_fits
 from bfs_tpu.algo.substrate import edge_weights
-from bfs_tpu.graph.csr import INF_DIST
+from bfs_tpu.graph.csr import INF_DIST, Graph, build_device_graph
 from bfs_tpu.graph.generators import (
     gnm_graph,
     path_graph,
@@ -237,3 +240,248 @@ def test_sssp_sharded_parity(graph, shards):
     np.testing.assert_array_equal(res.dist, base.dist)
     np.testing.assert_array_equal(res.parent, base.parent)
     assert res.rounds == base.rounds
+
+
+# ------------------------------------------------ stored float32 weights --
+# Graph500 kernel 3: one float32 weight per input edge, uniform in [0, 1),
+# stored both ways; a repeated edge keeps a weight of its own.
+
+def _weighted_rmat(scale: int, seed: int):
+    from bfs_tpu.graph.generators import rmat_edges
+
+    edges = rmat_edges(scale, 16, seed=seed).astype(np.int32)
+    w = np.random.default_rng(seed).random(edges.shape[0], dtype=np.float32)
+    w[:3] = 0.0  # exact zeros, as jax.random.uniform can draw
+    return Graph.from_undirected_edges(1 << scale, edges, weight=w)
+
+
+def _bits(x):
+    return np.asarray(x, np.float32).view(np.uint32)
+
+
+def _key(graph):
+    return int(np.flatnonzero(np.bincount(graph.src,
+                                          minlength=graph.num_vertices))[0])
+
+
+@pytest.mark.parametrize("scale,seed", [(8, 1), (9, 2), (10, 3)])
+def test_stored_float_weights_match_float32_dijkstra(scale, seed):
+    g = _weighted_rmat(scale, seed)
+    pairs = np.stack([g.src, g.dst], axis=1)
+    assert (g.src == g.dst).any(), "the graph keeps self-loops"
+    assert len(np.unique(pairs, axis=0)) < len(pairs), "and repeated edges"
+    root = _key(g)
+    odist, _ = dijkstra(g, g.weight, root)
+    res = sssp(g, root)
+    assert res.dist.dtype == np.float32 and res.max_weight is None
+    assert not res.packed and res.delta == np.inf
+    np.testing.assert_array_equal(_bits(res.dist), _bits(odist))
+    assert np.isinf(res.dist).any() and (res.parent == -1).any()
+    assert check_sssp(g, g.weight, res.dist, res.parent, root) == []
+
+
+@pytest.mark.parametrize("delta", [None, "inf", 0.5, 1.0 / 256])
+def test_float_delta_invariance(delta):
+    g = _weighted_rmat(9, 4)
+    root = _key(g)
+    odist, _ = dijkstra(g, g.weight, root)
+    res = sssp(g, root, delta=delta)
+    np.testing.assert_array_equal(_bits(res.dist), _bits(odist))
+    assert check_sssp(g, g.weight, res.dist, res.parent, root) == []
+
+
+def test_zero_weight_tie_gives_a_tree():
+    # root 0; 0-4 and 0-3 of 0.5; 4-1 and 3-2 of 0.25; 1-2 of 0.0: vertices
+    # 1 and 2 tie at 0.75, where a least-tight-neighbour rule gives
+    # parent[1] = 2 and parent[2] = 1, a cycle.
+    edges = np.array([[0, 4], [0, 3], [4, 1], [3, 2], [1, 2]])
+    w = np.array([0.5, 0.5, 0.25, 0.25, 0.0], np.float32)
+    g = Graph.from_undirected_edges(5, edges, weight=w)
+    res = sssp(g, 0)
+    np.testing.assert_array_equal(res.dist, [0, 0.75, 0.75, 0.5, 0.5])
+    assert res.parent.tolist() == [0, 4, 3, 0, 0]
+    assert check_sssp(g, g.weight, res.dist, res.parent, 0) == []
+    # An integer zero weight ties the same way.
+    gi = Graph.from_undirected_edges(5, edges, weight=(w * 4).astype(np.int32))
+    resi = sssp(gi, 0, packed=False)
+    assert resi.parent.tolist() == [0, 4, 3, 0, 0]
+    assert check_sssp(gi, gi.weight, resi.dist, resi.parent, 0) == []
+
+
+def test_tie_chain_fixed_in_rounds():
+    # A chain of zero-weight edges hangs off one strict parent: each tie
+    # round fixes the next vertex out, so the parents follow the chain.
+    edges = np.array([[0, 1], [1, 2], [2, 3], [3, 4]])
+    w = np.array([0.5, 0.0, 0.0, 0.0], np.float32)
+    g = Graph.from_undirected_edges(5, edges, weight=w)
+    res = sssp(g, 0)
+    assert res.parent.tolist() == [0, 0, 1, 2, 3]
+    assert check_sssp(g, g.weight, res.dist, res.parent, 0) == []
+
+
+def test_device_graph_keeps_each_weight_with_its_edge():
+    g = _weighted_rmat(7, 5)
+    w = np.arange(g.num_edges, dtype=np.float32)  # a weight names its edge
+    g = Graph(g.num_vertices, g.src, g.dst, w)
+    dg = build_device_graph(g, block=1000)
+    plain = build_device_graph(Graph(g.num_vertices, g.src, g.dst),
+                               block=1000)
+    np.testing.assert_array_equal(dg.src, plain.src)
+    np.testing.assert_array_equal(dg.dst, plain.dst)
+    assert dg.weight.shape == dg.src.shape and dg.weight.dtype == np.float32
+    real = dg.dst != dg.sentinel
+    assert (~real).any(), "the layout is padded"
+    idx = dg.weight[real].astype(np.int64)
+    np.testing.assert_array_equal(np.sort(idx), np.arange(g.num_edges))
+    np.testing.assert_array_equal(g.src[idx], dg.src[real])
+    np.testing.assert_array_equal(g.dst[idx], dg.dst[real])
+    assert (dg.weight[~real] == 0).all()
+    # A sharded layout holds no weights, and sharded SSSP refuses them.
+    assert build_device_graph(g, num_shards=2, block=64).weight is None
+    with pytest.raises(ValueError, match="one device"):
+        sssp_sharded(g, 0, num_shards=2)
+
+
+@pytest.mark.parametrize("bad", [-0.5, np.nan, np.inf])
+def test_bad_weights_raise(bad):
+    w = np.ones(4, np.float32)
+    w[2] = bad
+    edges = np.array([[0, 1], [1, 2], [2, 3], [3, 0]])
+    with pytest.raises(ValueError):
+        Graph.from_undirected_edges(4, edges, weight=w)
+    g = Graph.from_undirected_edges(4, edges)
+    with pytest.raises(ValueError):
+        dijkstra(g, np.concatenate([w, w]), 0)
+
+
+def test_weights_must_align_and_be_numbers():
+    edges = np.array([[0, 1], [1, 2]])
+    with pytest.raises(ValueError):
+        Graph.from_undirected_edges(3, edges, weight=np.ones(3, np.float32))
+    with pytest.raises(ValueError):
+        Graph(3, [0, 1], [1, 2], np.array(["a", "b"]))
+
+
+def test_stored_int_weights_equal_the_hash_path(graph):
+    # Integer weights as an operand: the hash's values stored on the graph
+    # give the hash path's numbers, canonical parents included.
+    w = edge_weights_np(graph.src, graph.dst, MAXW)
+    stored = Graph(graph.num_vertices, graph.src, graph.dst, w)
+    res = sssp(stored, SOURCE, delta=64)
+    base = sssp(graph, SOURCE, max_weight=MAXW)
+    np.testing.assert_array_equal(res.dist, base.dist)
+    np.testing.assert_array_equal(res.parent, base.parent)
+    assert res.rounds == base.rounds and res.max_weight is None
+
+
+def test_float_weights_never_take_the_packed_arm():
+    g = _weighted_rmat(7, 6)
+    assert not sssp(g, _key(g)).packed
+    with pytest.raises(ValueError):
+        sssp(g, _key(g), packed=True)
+
+
+def test_float_segmented_bit_identical(tmp_path):
+    g = _weighted_rmat(8, 7)
+    root = _key(g)
+    fused = sssp(g, root, delta=0.25)
+    res = sssp_segmented(g, root, ckpt=_mgr(tmp_path, k=2), delta=0.25)
+    np.testing.assert_array_equal(_bits(res.dist), _bits(fused.dist))
+    np.testing.assert_array_equal(res.parent, fused.parent)
+    assert res.rounds == fused.rounds
+
+
+def test_default_cap_raises_and_explicit_cap_returns(monkeypatch):
+    sssp_mod = importlib.import_module("bfs_tpu.algo.sssp")
+    g = path_graph(40)
+    partial = sssp(g, 0, max_rounds=3, packed=False)
+    assert np.count_nonzero(partial.dist != INF_DIST) < 40
+    monkeypatch.setattr(sssp_mod, "_rounds_cap", lambda v, m: 3 if m is None else m)
+    with pytest.raises(RuntimeError, match="still changing"):
+        sssp(g, 0, packed=False)
+
+
+def test_rounds_cap_is_twice_the_vertices():
+    from bfs_tpu.algo.sssp import _rounds_cap
+
+    assert _rounds_cap(100, None) == 202
+    assert _rounds_cap(100, 7) == 7
+    # A path under delta 1/1024 advances a bucket between most settles:
+    # still inside the bound.
+    edges = np.stack([np.arange(63), np.arange(1, 64)], axis=1)
+    g = Graph.from_undirected_edges(64, edges,
+                                    weight=np.ones(63, np.float32))
+    assert sssp(g, 0, delta=1.0 / 1024).rounds <= _rounds_cap(64, None)
+
+
+def test_resolve_delta_by_weights(monkeypatch):
+    monkeypatch.delenv("BFS_TPU_SSSP_DELTA", raising=False)
+    assert resolve_delta(floating=True) == np.inf
+    assert resolve_delta(0.25, floating=True) == 0.25
+    assert resolve_delta("0.5", floating=True) == 0.5
+    assert resolve_delta(0.25) == 1  # integer weights round a width up
+    monkeypatch.setenv("BFS_TPU_SSSP_DELTA", "0.125")
+    assert resolve_delta(floating=True) == 0.125
+    assert resolve_delta() == 1
+    monkeypatch.setenv("BFS_TPU_SSSP_DELTA", "inf")
+    assert resolve_delta(floating=True) == np.inf
+
+
+def test_sssp_spans_and_counters(monkeypatch):
+    from bfs_tpu.obs.registry import get_registry
+    from bfs_tpu.obs.spans import drain_events
+
+    monkeypatch.setenv("BFS_TPU_SPANS", "1")
+    g = _weighted_rmat(7, 8)
+    sssp(g, _key(g))  # compiles outside the recorded spans
+    drain_events()
+    reg = get_registry()
+    searches, steps = reg.count("sssp_searches"), reg.count("sssp_supersteps")
+    res = sssp(g, _key(g))
+    events = [e for e in drain_events() if e["name"].startswith("sssp.")]
+    names = [e["name"] for e in events]
+    assert sorted(names) == sorted(["sssp.search", "sssp.launch",
+                                    "sssp.parents", "sssp.device_wait",
+                                    "sssp.fetch"])
+    search = next(e for e in events if e["name"] == "sssp.search")
+    assert all(e["parent"] == search["id"] for e in events if e is not search)
+    fetch = next(e for e in events if e["name"] == "sssp.fetch")
+    n = g.num_vertices + 1
+    assert fetch["args"]["bytes"] == 4 * n + 4 * n + 4 + 1
+    assert reg.count("sssp_searches") == searches + 1
+    assert reg.count("sssp_supersteps") == steps + res.rounds
+
+
+def test_integer_weights_must_fit_int32():
+    edges = np.array([[0, 1]])
+    for bad in (2**31, -(2**32) + 5):
+        with pytest.raises(ValueError):
+            Graph.from_undirected_edges(2, edges,
+                                        weight=np.array([bad], np.int64))
+    g = Graph.from_undirected_edges(2, edges, weight=np.array([7], np.int64))
+    assert g.weight.dtype == np.int32 and g.weight.tolist() == [7, 7]
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.int32, np.uint32])
+@pytest.mark.parametrize("e,n", [(8, 3), (1000, 40), (4096, 7), (3072, 1),
+                                 (640, 200)])
+def test_combine_min_scan_form_equals_scatter(dtype, e, n):
+    # The scan form (rows of up to 128 edges, tails carried across rows,
+    # one gather per destination) gives the scatter's minima exactly:
+    # runs that span many rows, destinations with no in-edge (first and
+    # last among them), rows narrower than 128 where e is not a multiple.
+    from bfs_tpu.ops.relax import combine_min, dst_ends
+
+    rng = np.random.default_rng(e * 31 + n)
+    dst = np.sort(rng.integers(1, max(n - 1, 2), e)).astype(np.int32) % n
+    dst = np.sort(dst)
+    if dtype == np.float32:
+        vals = rng.random(e).astype(np.float32)
+        vals[rng.random(e) < 0.3] = np.inf
+    else:
+        vals = rng.integers(0, 1000, e).astype(dtype)
+    scatter = combine_min(jnp.asarray(vals), jnp.asarray(dst), n)
+    ends = dst_ends(jnp.asarray(dst), n)
+    scan = combine_min(jnp.asarray(vals), jnp.asarray(dst), n, ends)
+    np.testing.assert_array_equal(np.asarray(scan), np.asarray(scatter))
+    assert (np.asarray(ends)[np.bincount(dst, minlength=n) == 0] == e).all()

@@ -118,3 +118,73 @@ def test_check_directed_graph_no_false_positive():
     dist, parent = queue_bfs(g, 0)
     assert dist.tolist() == [0, 1, INF_DIST]
     assert check(g, dist, parent, 0) == []
+
+
+# ------------------------------------------------ float32 SSSP oracle --
+
+def _float_diamond():
+    # 0-1 and 0-2 of 0.1 and 0.3, 1-2 of 0.2, 2-3 of 0.7: float32 decides
+    # whether 0-1-2 beats 0-2, as on the device.
+    from bfs_tpu.oracle.sssp import dijkstra
+
+    edges = np.array([[0, 1], [0, 2], [1, 2], [2, 3]])
+    w = np.array([0.1, 0.3, 0.2, 0.7], np.float32)
+    g = Graph.from_undirected_edges(5, edges, weight=w)
+    return g, dijkstra(g, g.weight, 0)
+
+
+def test_dijkstra_float32_adds_in_float32():
+    g, (dist, parent) = _float_diamond()
+    f = np.float32
+    via1 = f(0.1) + f(0.2)
+    want2 = min(via1, f(0.3))
+    assert dist.dtype == np.float32
+    assert dist[2].view(np.uint32) == want2.view(np.uint32)
+    assert dist[3].view(np.uint32) == (want2 + f(0.7)).view(np.uint32)
+    assert np.isinf(dist[4]) and parent[4] == NO_PARENT
+    assert parent[0] == 0 and parent[3] == 2
+
+
+def test_check_sssp_float_tree_rule():
+    from bfs_tpu.oracle.sssp import check_sssp
+
+    g, (dist, parent) = _float_diamond()
+    assert check_sssp(g, g.weight, dist, parent, 0) == []
+    up = dist.copy()
+    up[3] = np.nextafter(up[3], np.float32(np.inf))  # one ulp too large
+    assert check_sssp(g, g.weight, up, parent, 0)
+    missing = dist.copy()
+    missing[3] = np.inf  # reachable, reported unreached
+    assert check_sssp(g, g.weight, missing, parent, 0)
+    cycle = parent.copy()
+    cycle[1], cycle[2] = 2, 1
+    assert any("chain" in v or "tight" in v
+               for v in check_sssp(g, g.weight, dist, cycle, 0))
+    stray = parent.copy()
+    stray[4] = 3  # an unreached vertex with a parent
+    assert check_sssp(g, g.weight, dist, stray, 0)
+
+
+def test_check_sssp_finds_a_tied_parent_cycle():
+    # 1 and 2 tie through a zero-weight edge: parents pointing at each
+    # other are tight edges but no tree.
+    from bfs_tpu.oracle.sssp import check_sssp, dijkstra
+
+    edges = np.array([[0, 4], [0, 3], [4, 1], [3, 2], [1, 2]])
+    w = np.array([0.5, 0.5, 0.25, 0.25, 0.0], np.float32)
+    g = Graph.from_undirected_edges(5, edges, weight=w)
+    dist, parent = dijkstra(g, g.weight, 0)
+    assert check_sssp(g, g.weight, dist, parent, 0) == []
+    cyc = parent.copy()
+    cyc[1], cyc[2] = 2, 1
+    assert any("chain" in v for v in check_sssp(g, g.weight, dist, cyc, 0))
+
+
+def test_dijkstra_integer_weights_stay_at_least_one():
+    from bfs_tpu.oracle.sssp import dijkstra
+
+    g = path_graph(4)
+    with pytest.raises(ValueError):
+        dijkstra(g, np.zeros(g.num_edges, np.int32), 0)
+    dist, _ = dijkstra(g, np.ones(g.num_edges, np.int32), 0)
+    assert dist.dtype == np.int32 and dist.tolist() == [0, 1, 2, 3]

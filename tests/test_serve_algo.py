@@ -67,3 +67,17 @@ def test_registry_algo_leaves_no_pins(registry):
 def test_registry_cc_rejects_unknown_engine(registry):
     with pytest.raises(ValueError, match="unknown engine"):
         registry_cc(registry, "g", engine="relay")
+
+
+def test_registry_sssp_refuses_stored_weights(graph):
+    # Registry SSSP runs on the endpoint hash: a graph that stores its own
+    # weights would otherwise be answered with other weights.
+    from bfs_tpu.graph.csr import Graph
+
+    weighted = Graph(graph.num_vertices, graph.src, graph.dst,
+                     np.ones(graph.num_edges, np.float32))
+    reg = GraphRegistry()
+    reg.register("w", weighted)
+    with pytest.raises(ValueError, match="stores its weights"):
+        registry_sssp(reg, "w", SOURCE)
+    assert reg.get("w").pins == 0
