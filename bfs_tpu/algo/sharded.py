@@ -75,7 +75,7 @@ def _sssp_sharded_fused(
         inner,
         mesh=mesh,
         in_specs=(P(GRAPH_AXIS, None), P(GRAPH_AXIS, None), P()),
-        out_specs=SsspState(P(), P(), P(), P(), P()),
+        out_specs=SsspState(*(P(),) * len(SsspState._fields)),
         axis_names={GRAPH_AXIS},
     )
     return fn(src, dst, source)
@@ -144,11 +144,12 @@ def sssp_sharded(
     flat_dst = jnp.asarray(np.ascontiguousarray(dg.dst.reshape(-1)))
     # The shards, laid end to end, are not dst-sorted as a whole: no
     # ``ends``, so the parent pass combines by the scatter.
-    dist, parent, rounds, changed = _parents_and_fetch(
-        state.dist, state.rounds, state.changed, flat_src, flat_dst,
+    dist, parent, (rounds, changed) = _parents_and_fetch(
+        state.dist, (state.rounds, state.changed), flat_src, flat_dst,
         _hash_weights(flat_src, flat_dst, max_weight=max_weight),
         None, source, v + 1,
     )
+    rounds = int(rounds)
     _converged(changed, rounds, max_rounds, source)
     return SsspResult(
         dist=dist[:v], parent=parent[:v], rounds=rounds,

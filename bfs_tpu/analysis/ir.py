@@ -1048,29 +1048,39 @@ def _spec_sharded_relay(flavor: str = "dense"):
     )
 
 
+#: Push tiers small enough that the tiny graph's searches take both the
+#: push arm and the sweep.
+_TINY_PUSH_TIERS = ((8, 32), (32, 128))
+
+
 def _spec_algo_sssp_fused(packed: bool, floating: bool = False):
     """The semiring SSSP programs (ISSUE 16): min-plus supersteps over the
     weight operand — the endpoint hash under an unpacked int32 or packed
     dist:16|parent:16 carry, or stored float32 weights under a float32
     carry (Graph500 kernel 3) — same HBM/donation rules as the BFS fused
-    programs."""
+    programs.  The unpacked programs compile the push arm beside the
+    sweep (the out-row view and two tiers)."""
     import jax.numpy as jnp
 
     from ..algo.sssp import _sssp_fused
 
-    dg, src, dst, w, ends = _memo("sssp_operands", _tiny_sssp_operands)
+    dg, src, dst, w, ends, out = _memo("sssp_operands", _tiny_sssp_operands)
     v = dg.num_vertices
     if floating:
+        from ..algo.sssp import _build_operands
+
         w = w.astype(jnp.float32) / 32
+        ends, out = _build_operands(src, dst, w, num_slots=v + 1)
     flavor = "_packed" if packed else "_float" if floating else ""
     return Program(
         name=f"algo.sssp_fused{flavor}",
         path="bfs_tpu/algo/sssp.py",
         fn=_sssp_fused,
-        args=(src, dst, w, ends, jnp.int32(0)),
+        args=(src, dst, w, ends, out, jnp.int32(0)),
         static_kwargs=dict(
             num_vertices=v, delta=float("inf") if floating else 64,
             max_rounds=64, packed=packed,
+            tiers=() if packed else _TINY_PUSH_TIERS,
         ),
         v_elements=v, packed=packed, budget_bytes=_hbm_envelope(),
     )
@@ -1078,17 +1088,19 @@ def _spec_algo_sssp_fused(packed: bool, floating: bool = False):
 
 def _tiny_sssp_operands():
     """The tiny graph's device edges with hash weights in ``[1, 31]``,
-    and each destination's last edge (the scan form of the combine)."""
+    each destination's last edge (the scan form of the combine) and the
+    out-row view (the push arm)."""
     import jax.numpy as jnp
 
+    from ..algo.sssp import _build_operands
     from ..algo.substrate import edge_weights
     from ..graph.csr import build_device_graph
-    from ..ops.relax import dst_ends
 
     dg = _memo("dg", lambda: build_device_graph(_tiny_graph()))
     src, dst = jnp.asarray(dg.src), jnp.asarray(dg.dst)
-    ends = dst_ends(dst, dg.num_vertices + 1)
-    return dg, src, dst, edge_weights(src, dst, 31), ends
+    w = edge_weights(src, dst, 31)
+    ends, out = _build_operands(src, dst, w, num_slots=dg.num_vertices + 1)
+    return dg, src, dst, w, ends, out
 
 
 def _spec_algo_sssp_segment():
@@ -1096,13 +1108,15 @@ def _spec_algo_sssp_segment():
 
     from ..algo.sssp import _sssp_segment, init_sssp_state
 
-    dg, src, dst, w, ends = _memo("sssp_operands", _tiny_sssp_operands)
+    dg, src, dst, w, ends, out = _memo("sssp_operands", _tiny_sssp_operands)
     v = dg.num_vertices
     return Program(
         name="algo.sssp_segment", path="bfs_tpu/algo/sssp.py",
         fn=_sssp_segment,
-        args=(init_sssp_state(v, 0, 64), jnp.int32(8), src, dst, w, ends),
-        static_kwargs=dict(num_vertices=v, delta=64, packed=False),
+        args=(init_sssp_state(v, 0, 64), jnp.int32(8), src, dst, w, ends,
+              out),
+        static_kwargs=dict(num_vertices=v, delta=64, packed=False,
+                           tiers=_TINY_PUSH_TIERS),
         v_elements=v, donate={0: "state"}, budget_bytes=_hbm_envelope(),
     )
 
@@ -1114,7 +1128,7 @@ def _spec_algo_sssp_parents():
 
     from ..algo.sssp import _sssp_parents
 
-    dg, src, dst, w, ends = _memo("sssp_operands", _tiny_sssp_operands)
+    dg, src, dst, w, ends, _ = _memo("sssp_operands", _tiny_sssp_operands)
     v = dg.num_vertices
     dist = jnp.zeros((v + 1,), jnp.int32)
     return Program(
@@ -1179,6 +1193,7 @@ def _spec_algo_sssp_sharded():
 
     _need_devices(2)
     from ..algo.sharded import _sssp_sharded_fused
+    from ..algo.sssp import SsspState
     from ..graph.csr import build_device_graph
 
     mesh = _memo("mesh2", lambda: make_mesh(graph=2, batch=1))
@@ -1201,8 +1216,8 @@ def _spec_algo_sssp_sharded():
         v_elements=v, budget_bytes=_hbm_envelope(),
         mesh_axes=frozenset({"graph"}),
         required_axes=frozenset({"graph"}),
-        # SsspState(dist, dirty, threshold, rounds, changed) — replicated.
-        expected_out_names=(frozenset(),) * 5,
+        # SsspState — every field replicated.
+        expected_out_names=(frozenset(),) * len(SsspState._fields),
     )
 
 

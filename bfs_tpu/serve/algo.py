@@ -9,16 +9,23 @@ push/pull operands through :meth:`GraphRegistry.acquire_for`, and run
 the fused algo programs against them — no per-call H2B upload, and the
 registry's HBM budget governs the algorithms exactly as it governs BFS.
 
-Weights need no residency of their own: the SSSP arm recomputes them
-on device from the resident edge endpoints
-(:func:`bfs_tpu.algo.substrate.edge_weights`), and refuses a graph that
-stores weights of its own.
+SSSP's per-graph operands (the endpoint-hash weights
+:func:`bfs_tpu.algo.substrate.edge_weights`, ``dst_ends`` and, where
+the push arm runs, the out-row view) are built once from the resident
+edge endpoints and held as an entry of their own under the same budget
+(:meth:`GraphRegistry.acquire_sssp`).  Registry SSSP refuses a graph
+that stores weights of its own.
 """
 
 from __future__ import annotations
 
 from ..algo.cc import CcResult, cc_device, cc_device_pull
-from ..algo.sssp import SsspResult, sssp_device
+from ..algo.sssp import (
+    DEFAULT_MAX_WEIGHT,
+    SsspResult,
+    sssp_device,
+    uses_packed16,
+)
 from .registry import GraphRegistry
 
 __all__ = ["registry_sssp", "registry_cc"]
@@ -34,11 +41,14 @@ def registry_sssp(
     registry: GraphRegistry,
     name: str,
     source: int = 0,
+    *,
+    max_weight: int = DEFAULT_MAX_WEIGHT,
+    packed: bool | None = None,
     **kwargs,
 ) -> SsspResult:
-    """Weighted SSSP on a registered graph's resident push operands.
-    ``kwargs`` pass through to :func:`bfs_tpu.algo.sssp.sssp_device`
-    (max_weight / delta / max_rounds / packed)."""
+    """Weighted SSSP on a registered graph's resident push operands and
+    its resident SSSP operands.  ``kwargs`` pass through to
+    :func:`bfs_tpu.algo.sssp.sssp_device` (delta / max_rounds)."""
     rec = registry.pin(name)
     try:
         if rec.graph is not None and rec.graph.weight is not None:
@@ -46,9 +56,13 @@ def registry_sssp(
                 f"graph {name!r} stores its weights; registry SSSP runs on "
                 "the endpoint hash (bfs_tpu.algo.sssp.sssp takes them)")
         src_dev, dst_dev = registry.acquire_for(rec, "push")
+        v = _num_vertices(registry, rec, "push")
+        push = not uses_packed16(v, floating=False, packed=packed)
+        operands = registry.acquire_sssp(rec, max_weight=max_weight,
+                                         push=push)
         return sssp_device(
-            src_dev, dst_dev, _num_vertices(registry, rec, "push"),
-            source, **kwargs,
+            src_dev, dst_dev, v, source, max_weight=max_weight,
+            packed=packed, operands=operands, **kwargs,
         )
     finally:
         registry.unpin(rec)

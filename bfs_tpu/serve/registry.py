@@ -109,6 +109,17 @@ def _push_device_bytes(dg: DeviceGraph) -> int:
     return 4 * (int(np.asarray(dg.src).size) + int(np.asarray(dg.dst).size))
 
 
+def _upload(engine: str, layout):
+    """An engine's device operands from its host layout."""
+    import jax.numpy as jnp
+
+    if engine == "pull":
+        return device_ell(layout)
+    if engine == "push":
+        return (jnp.asarray(layout.src), jnp.asarray(layout.dst))
+    return layout  # relay: tensors uploaded at engine init
+
+
 class GraphRegistry:
     """Named graph epochs + memoized layouts + budgeted device residency.
 
@@ -461,9 +472,45 @@ class GraphRegistry:
         entry most-recently-used and evicts LRU entries (via
         :func:`drop_device_operands` for pull) until the budget holds —
         skipping entries whose epoch is pinned by in-flight work."""
-        import jax.numpy as jnp
-
         layout = self._layout_for(rec, engine)
+        if engine == "pull":
+            nbytes = _pull_device_bytes(layout)
+        elif engine == "push":
+            nbytes = _push_device_bytes(layout)
+        else:
+            rg = layout.relay_graph
+            nbytes = int(rg.vperm_masks.nbytes + rg.net_masks.nbytes)
+        return self._acquire(rec, engine, nbytes,
+                             lambda: _upload(engine, layout))
+
+    def acquire_sssp(self, rec: RegisteredGraph, *, max_weight: int,
+                     push: bool):
+        """SSSP's per-graph device operands for one epoch
+        (:func:`bfs_tpu.algo.sssp.graph_operands`: the endpoint hash
+        under ``max_weight``, ``dst_ends``, and the out-row view where
+        ``push``), built from the resident push operands and held and
+        budgeted like them, as their own entry ``sssp/w<max_weight>``
+        (``/push`` with the view)."""
+        from ..algo.sssp import graph_operands, graph_operands_nbytes
+
+        layout = self._layout_for(rec, "push")
+        v = int(layout.num_vertices)
+        nbytes = graph_operands_nbytes(v, int(np.asarray(layout.src).size),
+                                       push=push)
+
+        def upload():
+            src, dst = self.acquire_for(rec, "push")
+            return graph_operands(src, dst, v, max_weight=max_weight,
+                                  push=push)
+
+        engine = f"sssp/w{int(max_weight)}" + ("/push" if push else "")
+        return self._acquire(rec, engine, nbytes, upload)
+
+    def _acquire(self, rec: RegisteredGraph, engine: str, nbytes: int,
+                 upload):
+        """The residency of :meth:`acquire_for`: the entry ``(rec,
+        engine)`` if resident, else ``upload()`` of ``nbytes`` within
+        budget."""
         key = (rec.name, rec.epoch, engine, _engine_env_fingerprint())
         with self._lock:
             if key in self._resident:
@@ -473,13 +520,6 @@ class GraphRegistry:
                 # LRU entries until the budget holds again.
                 self._make_room(0, keep=key)
                 return self._resident[key][1]
-            if engine == "pull":
-                nbytes = _pull_device_bytes(layout)
-            elif engine == "push":
-                nbytes = _push_device_bytes(layout)
-            else:
-                rg = layout.relay_graph
-                nbytes = int(rg.vperm_masks.nbytes + rg.net_masks.nbytes)
             # Make room BEFORE the out-of-lock upload: evicting victims
             # only after the new operands are resident would peak HBM at
             # budget + incoming — the overshoot the budget exists to
@@ -492,12 +532,7 @@ class GraphRegistry:
         # register on every graph — the exact whole-server wedge the
         # watchdog exists to prevent.  A concurrent duplicate upload is
         # harmless (keep-first below; device_ell memoizes on the layout).
-        if engine == "pull":
-            operands = device_ell(layout)
-        elif engine == "push":
-            operands = (jnp.asarray(layout.src), jnp.asarray(layout.dst))
-        else:
-            operands = layout  # tensors uploaded at engine init
+        operands = upload()
         with self._lock:
             if key in self._resident:  # lost an upload race: keep first
                 self._resident.move_to_end(key)
@@ -590,7 +625,7 @@ class GraphRegistry:
             # but the engine memoizes it internally — drop the whole
             # object and rebuild on next acquire).
             rec.layouts.pop(engine, None)
-        # push: the device (src, dst) pair lived only in the resident entry.
+        # push, sssp/*: the device arrays lived only in the resident entry.
         self.evictions += 1
         if self.metrics is not None:
             self.metrics.bump("evictions")

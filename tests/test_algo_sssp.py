@@ -8,13 +8,16 @@ bucket invariance (delta in {1, 17, inf, default} -> one fixpoint); the
 packed16 (dist:16|parent:16) arm's schedule identity with the unpacked
 carry; the truncation canary -> unpacked fallback; fused-vs-segmented
 bit-identity incl. the in-process kill/resume chaos smoke; x2/x8
-edge-sharded parity; the on-device invariant counters; and the semiring
-registry / hash-weight / delta-knob contracts.
+edge-sharded parity; the on-device invariant counters; the semiring
+registry / hash-weight / delta-knob contracts; and the push arm (bit for
+bit the sweep's carries, its tier predicate) with the per-graph operands
+it reads (the out-row view, cached while the edge arrays live).
 """
 
 import importlib
 import os
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -38,6 +41,7 @@ from bfs_tpu.graph.generators import (
     star_graph,
 )
 from bfs_tpu.oracle import check_sssp, dijkstra, sssp_device_check
+from bfs_tpu.ops.relax import dst_ends
 from bfs_tpu.resilience import faults
 from bfs_tpu.resilience.faults import FaultInjected
 from bfs_tpu.resilience.superstep_ckpt import CkptConfig, SuperstepCheckpointer
@@ -437,6 +441,7 @@ def test_sssp_spans_and_counters(monkeypatch):
     drain_events()
     reg = get_registry()
     searches, steps = reg.count("sssp_searches"), reg.count("sssp_supersteps")
+    pushes = reg.count("sssp_push_supersteps")
     res = sssp(g, _key(g))
     events = [e for e in drain_events() if e["name"].startswith("sssp.")]
     names = [e["name"] for e in events]
@@ -447,9 +452,11 @@ def test_sssp_spans_and_counters(monkeypatch):
     assert all(e["parent"] == search["id"] for e in events if e is not search)
     fetch = next(e for e in events if e["name"] == "sssp.fetch")
     n = g.num_vertices + 1
-    assert fetch["args"]["bytes"] == 4 * n + 4 * n + 4 + 1
+    # dist, parent, rounds, changed, push_rounds
+    assert fetch["args"]["bytes"] == 4 * n + 4 * n + 4 + 1 + 4
     assert reg.count("sssp_searches") == searches + 1
     assert reg.count("sssp_supersteps") == steps + res.rounds
+    assert reg.count("sssp_push_supersteps") == pushes + res.push_rounds
 
 
 def test_integer_weights_must_fit_int32():
@@ -485,3 +492,270 @@ def test_combine_min_scan_form_equals_scatter(dtype, e, n):
     scan = combine_min(jnp.asarray(vals), jnp.asarray(dst), n, ends)
     np.testing.assert_array_equal(np.asarray(scan), np.asarray(scatter))
     assert (np.asarray(ends)[np.bincount(dst, minlength=n) == 0] == e).all()
+
+
+# ------------------------------------------------------------ push arm --
+# The unpacked one-device superstep expands a small frontier's own
+# out-edges (the push arm) in place of the sweep over every edge; every
+# carry is bit for bit the sweep's.
+
+sssp_mod = importlib.import_module("bfs_tpu.algo.sssp")
+
+#: Tiers small enough that the test graphs take both arms.
+TINY_TIERS = ((16, 128), (256, 2048))
+
+
+def _float_path(n: int):
+    edges = np.stack([np.arange(n - 1), np.arange(1, n)], axis=1)
+    w = np.random.default_rng(n).random(n - 1, dtype=np.float32)
+    return Graph.from_undirected_edges(n, edges, weight=w)
+
+
+PUSH_GRAPHS = {
+    "rmat10": lambda: _weighted_rmat(10, 11),
+    "rmat11": lambda: _weighted_rmat(11, 12),
+    "rmat12": lambda: _weighted_rmat(12, 13),
+    "path": lambda: _float_path(700),
+}
+
+
+def _push_graph(name: str, weights: str):
+    key = ("push", name)
+    if key not in _cache:
+        _cache[key] = PUSH_GRAPHS[name]()
+    g = _cache[key]
+    if weights == "hash":
+        g = Graph(g.num_vertices, g.src, g.dst)
+    return g
+
+
+def _device(graph):
+    dg = build_device_graph(graph)
+    w = None if dg.weight is None else jnp.asarray(dg.weight)
+    return dg.num_vertices, jnp.asarray(dg.src), jnp.asarray(dg.dst), w
+
+
+def _search(dev, root, *, tiers, delta):
+    """One search through the fused program and the parent pass, with
+    the push tiers given (none: the sweep every superstep)."""
+    v, src, dst, w = dev
+    if w is None:
+        w = sssp_mod._hash_weights(src, dst, max_weight=MAXW)
+    ends, out = sssp_mod._build_operands(src, dst, w, num_slots=v + 1)
+    state = sssp_mod._sssp_fused(
+        src, dst, w, ends, out, jnp.int32(root),
+        num_vertices=v, delta=delta, max_rounds=2 * (v + 1), packed=False,
+        tiers=tiers,
+    )
+    parent = sssp_mod._sssp_parents(state.dist, src, dst, w, ends,
+                                    jnp.int32(root), v + 1)
+    return (np.asarray(state.dist), np.asarray(parent), int(state.rounds),
+            int(state.push_rounds))
+
+
+def _roots(graph, k=3):
+    deg = np.bincount(graph.src, minlength=graph.num_vertices)
+    live = np.flatnonzero(deg)
+    return [int(live[0]), int(live[len(live) // 2]), int(live[-1])][:k]
+
+
+@pytest.mark.parametrize("delta", ["inf", "finite"])
+@pytest.mark.parametrize("weights", ["float32", "hash"])
+@pytest.mark.parametrize("name", sorted(PUSH_GRAPHS))
+def test_push_arm_matches_sweep(name, weights, delta):
+    g = _push_graph(name, weights)
+    dev = _device(g)
+    floating = weights == "float32"
+    d = resolve_delta(delta if delta == "inf" else (0.25 if floating else 9),
+                      floating=floating)
+    pushed = 0
+    for root in _roots(g):
+        ref = _search(dev, root, tiers=(), delta=d)
+        got = _search(dev, root, tiers=TINY_TIERS, delta=d)
+        assert ref[3] == 0
+        np.testing.assert_array_equal(_bits(got[0]) if floating else got[0],
+                                      _bits(ref[0]) if floating else ref[0])
+        np.testing.assert_array_equal(got[1], ref[1])
+        assert got[2] == ref[2]
+        assert got[3] <= got[2]
+        pushed += got[3]
+    assert pushed > 0
+
+
+@pytest.fixture
+def push_on(monkeypatch):
+    """Route the public entry points through :data:`TINY_TIERS`."""
+    monkeypatch.setattr(sssp_mod, "PUSH_TIERS", TINY_TIERS)
+    monkeypatch.setattr(sssp_mod, "PUSH_MIN_SHRINK", 1)
+
+
+@pytest.mark.parametrize("tiers", [(), ((1, 1),)])
+def test_push_rounds_zero_when_budgets_force_the_sweep(push_on, monkeypatch,
+                                                       tiers):
+    # From a 64-star's hub: the hub (63 out-edges), then its 63 leaves
+    # (over the first tier's 16 vertices; the second tier exceeds the
+    # graph).  The hub pushes under TINY_TIERS, and sweeps under a budget
+    # of one vertex and one edge.
+    g = star_graph(64)
+    on = sssp(g, 0, delta="inf", max_weight=MAXW, packed=False)
+    assert (on.push_rounds, on.rounds) == (1, 2)
+    monkeypatch.setattr(sssp_mod, "PUSH_TIERS", tiers)
+    off = sssp(g, 0, delta="inf", max_weight=MAXW, packed=False)
+    assert off.push_rounds == 0
+    np.testing.assert_array_equal(off.dist, on.dist)
+    np.testing.assert_array_equal(off.parent, on.parent)
+    assert off.rounds == on.rounds
+
+
+@pytest.mark.parametrize("nf,nd,arm", [
+    (4, 16, 0), (4, 17, 1), (4, 64, 1), (4, 65, 2), (5, 1, 2), (0, 0, 0),
+])
+def test_push_arm_predicate(nf, nd, arm):
+    tiers = ((4, 16), (4, 64))
+    got = sssp_mod._push_arm(jnp.int32(nf), jnp.int32(nd), tiers)
+    assert int(got) == arm
+
+
+@pytest.mark.parametrize("budget,pushes", [(63, 2), (62, 1)])
+def test_hub_over_the_edge_budget_takes_the_sweep(budget, pushes):
+    # From a leaf of a 64-star: the leaf (1 out-edge), then the hub (63),
+    # then 62 leaves (over the vertex budget).  The hub's superstep pushes
+    # where its out-degree is the edge budget and sweeps one above it.
+    g = star_graph(64)
+    dev = _device(g)
+    d = resolve_delta("inf")
+    ref = _search(dev, 5, tiers=(), delta=d)
+    got = _search(dev, 5, tiers=((4, budget),), delta=d)
+    assert got[2] == ref[2] == 3
+    assert got[3] == pushes
+    np.testing.assert_array_equal(got[0], ref[0])
+    np.testing.assert_array_equal(got[1], ref[1])
+
+
+@pytest.mark.parametrize("weights", ["float32", "hash"])
+def test_segmented_equals_fused_with_push(push_on, tmp_path, weights):
+    g = _push_graph("rmat10", weights)
+    root = _key(g)
+    delta = 0.25 if weights == "float32" else 9
+    fused = sssp(g, root, delta=delta, max_weight=MAXW, packed=False)
+    assert fused.push_rounds > 0
+    for k in (1, 2, 5):
+        res = sssp_segmented(g, root, ckpt=_mgr(tmp_path / f"k{k}", k=k),
+                             delta=delta, max_weight=MAXW, packed=False)
+        np.testing.assert_array_equal(res.dist, fused.dist)
+        np.testing.assert_array_equal(res.parent, fused.parent)
+        assert res.rounds == fused.rounds
+        assert res.push_rounds == fused.push_rounds
+
+
+def test_push_tiers_follow_the_graph():
+    tiers, shrink = sssp_mod.PUSH_TIERS, sssp_mod.PUSH_MIN_SHRINK
+    # Graph500 scale 20's padded edges keep both tiers.
+    assert sssp_mod.push_tiers((1 << 20) + 1, 33_552_384) == tiers
+    # The vertex budget clamps to the graph; a tier near the graph's own
+    # size would cost more than the sweep, and a small graph has none.
+    (bv, be), = sssp_mod.push_tiers(1000, shrink * tiers[0][1])
+    assert (bv, be) == (1000, tiers[0][1])
+    assert sssp_mod.push_tiers(1000, shrink * tiers[0][1] - 1) == ()
+
+
+def test_ir_spec_takes_both_arms():
+    from bfs_tpu.analysis.ir import PROGRAM_SPECS
+
+    for name in ("algo.sssp_fused", "algo.sssp_fused_float"):
+        prog = PROGRAM_SPECS[name]()
+        state = prog.fn(*prog.args, **prog.static_kwargs)
+        assert 0 < int(state.push_rounds) < int(state.rounds), name
+
+
+# ----------------------------------------------------- per-graph operands --
+def test_out_rows_equal_a_stable_sort_by_source(push_on):
+    g = _push_graph("rmat10", "float32")
+    v, src, dst, w = _device(g)
+    ops = sssp_mod.graph_operands(src, dst, v, weight=w)
+    s, d, wn = np.asarray(src), np.asarray(dst), np.asarray(w)
+    order = np.argsort(s, kind="stable")
+    np.testing.assert_array_equal(np.asarray(ops.out.dst), d[order])
+    np.testing.assert_array_equal(_bits(ops.out.w), _bits(wn[order]))
+    indptr = np.concatenate([[0], np.cumsum(np.bincount(s, minlength=v + 1))])
+    np.testing.assert_array_equal(np.asarray(ops.out.indptr), indptr)
+    assert indptr[v] == g.num_edges, "the padding lies in row V"
+    np.testing.assert_array_equal(np.asarray(ops.ends),
+                                  np.asarray(dst_ends(dst, v + 1)))
+
+
+def test_second_search_builds_nothing():
+    from bfs_tpu.obs.registry import get_registry
+
+    reg = get_registry()
+    g = _push_graph("rmat10", "float32")
+    v, src, dst, w = _device(g)
+    root = _key(g)
+    built = reg.count("sssp_operands_built")
+    first = sssp_mod.sssp_device(src, dst, v, root, weight=w)
+    assert reg.count("sssp_operands_built") == built + 1
+    reused = reg.count("sssp_operands_reused")
+    second = sssp_mod.sssp_device(src, dst, v, root, weight=w)
+    assert reg.count("sssp_operands_built") == built + 1
+    assert reg.count("sssp_operands_reused") == reused + 1
+    np.testing.assert_array_equal(_bits(second.dist), _bits(first.dist))
+    # The hash weights are part of the entry too.
+    sssp_mod.sssp_device(src, dst, v, root, max_weight=MAXW)
+    sssp_mod.sssp_device(src, dst, v, root, max_weight=MAXW)
+    assert reg.count("sssp_operands_built") == built + 2
+
+
+def test_operands_go_with_their_arrays():
+    import gc
+
+    g = _push_graph("rmat10", "float32")
+    v, src, dst, w = _device(g)
+    held = set(sssp_mod._OPERANDS)
+    sssp_mod._kept_operands(src, dst, v, weight=w, max_weight=MAXW, push=True)
+    sssp_mod._kept_operands(src, dst, v, weight=None, max_weight=MAXW,
+                            push=True)
+    assert len(set(sssp_mod._OPERANDS) - held) == 2
+    del w
+    gc.collect()
+    assert len(set(sssp_mod._OPERANDS) - held) == 1, "weights gone: entry gone"
+    del src
+    gc.collect()
+    assert set(sssp_mod._OPERANDS) - held == set()
+
+
+@pytest.mark.parametrize("case,builds", [
+    ("small", 0),  # no push tiers: nothing reads a view
+    ("packed", 0),  # the packed carry never pushes
+    ("segmented-packed", 0),
+    ("unpacked", 1),
+    ("segmented-unpacked", 1),
+])
+def test_out_rows_built_only_where_the_push_arm_reads_them(
+        monkeypatch, tmp_path, case, builds):
+    g = _push_graph("rmat10", "hash")
+    if case != "small":
+        monkeypatch.setattr(sssp_mod, "PUSH_TIERS", TINY_TIERS)
+        monkeypatch.setattr(sssp_mod, "PUSH_MIN_SHRINK", 1)
+    calls = []
+    real = sssp_mod._build_operands
+    monkeypatch.setattr(sssp_mod, "_build_operands",
+                        lambda *a, **k: calls.append(1) or real(*a, **k))
+    packed = "unpacked" not in case
+    if case.startswith("segmented"):
+        res = sssp_segmented(g, _key(g), ckpt=_mgr(tmp_path, k=4),
+                             max_weight=MAXW, packed=packed)
+    else:
+        res = sssp(g, _key(g), max_weight=MAXW, packed=packed)
+    assert len(calls) == builds
+    assert res.packed == packed
+    assert (res.push_rounds > 0) == bool(builds)
+
+
+@pytest.mark.parametrize("push", [True, False])
+def test_graph_operands_nbytes_counts_what_is_built(push_on, push):
+    g = _push_graph("rmat11", "hash")
+    v, src, dst, _ = _device(g)
+    ops = sssp_mod.graph_operands(src, dst, v, max_weight=MAXW, push=push)
+    assert (ops.out is not None) == push
+    held = sum(int(a.nbytes) for a in jax.tree.leaves(ops))
+    assert sssp_mod.graph_operands_nbytes(v, src.shape[0], push=push) == held
